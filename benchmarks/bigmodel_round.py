@@ -42,9 +42,12 @@ SEED = 42
 P_ROUND = 16
 GATEWAYS = 4
 CHUNK = 1 << 18          # 2·16·262144·4 B ≈ 33.5 MB streamed working set
+# transformer-shaped rounds: (d_model, vocab, layers)
+QUICK_SHAPE = (256, 2048, 4)      # ≈ 3.7M params (CI-sized)
+FULL_SHAPE = (1024, 8192, 4)      # ≈ 58.7M params
 
 
-def _transformer_stacked(d_model: int, vocab: int, layers: int, P: int,
+def transformer_stacked(d_model: int, vocab: int, layers: int, P: int,
                          dtype=jnp.bfloat16, seed: int = 0):
     """Stacked transformer-shaped update/gradient pytrees (leading P axis),
     bf16 like real training deltas; f32 accumulation happens per chunk."""
@@ -69,12 +72,12 @@ def _transformer_stacked(d_model: int, vocab: int, layers: int, P: int,
     return deltas, grads, template, n
 
 
-def _cohorts(P: int, gws: int) -> List[List[int]]:
+def cohorts(P: int, gws: int) -> List[List[int]]:
     per = P // gws
     return [list(range(g * per, (g + 1) * per)) for g in range(gws)]
 
 
-def _round_once(eng, template, deltas, grads, cohorts):
+def round_once(eng, template, deltas, grads, cohorts):
     """One full tier-tree round through the engine-agnostic context API:
     gateway solves → cloud γ stage → combine into the parameters."""
     ctx = eng.begin_round(deltas, grads)
@@ -87,12 +90,12 @@ def _round_once(eng, template, deltas, grads, cohorts):
 
 
 def _time_rounds(eng, template, deltas, grads, cohorts, reps: int) -> float:
-    _, _, p, _ = _round_once(eng, template, deltas, grads, cohorts)
+    _, _, p, _ = round_once(eng, template, deltas, grads, cohorts)
     jax.block_until_ready(p)                      # warm-up pays the compiles
     ts = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        _, _, p, _ = _round_once(eng, template, deltas, grads, cohorts)
+        _, _, p, _ = round_once(eng, template, deltas, grads, cohorts)
         jax.block_until_ready(p)
         ts.append(time.perf_counter() - t0)
     return float(np.median(ts))
@@ -112,15 +115,16 @@ def _accum_oracle_record(quick: bool) -> dict:
     }
 
 
-def _logreg_record(rounds: int) -> dict:
+def logreg_fleet_problem():
+    """The headline 64-device / 4-gateway bimodal logreg fleet:
+    ``(dataset, params, HierConfig, topology)`` for ``run_hier_simulation``
+    with ``logistic_loss`` / ``logistic_apply``."""
     from repro.data import make_synthetic
     from repro.data.federated import FederatedDataset
     from repro.edge import bimodal_fleet
-    from repro.fl import run_hier_simulation
     from repro.hier import HierConfig, two_tier_topology
     from repro.models import get_model
     from repro.models.config import ArchConfig
-    from repro.models.logistic import logistic_apply, logistic_loss
 
     n_dev = 64
     xs, ys = make_synthetic(1.0, 1.0, num_devices=n_dev,
@@ -133,7 +137,14 @@ def _logreg_record(rounds: int) -> dict:
     fleet = bimodal_fleet(n_dev, slowdown=10.0, dropout_slow=0.05, seed=0)
     cfg = HierConfig(aggregator="hier_contextual", lr=0.2, batch_size=10,
                      min_epochs=1, max_epochs=10)
-    topo = two_tier_topology(fleet, GATEWAYS)
+    return ds, params, cfg, two_tier_topology(fleet, GATEWAYS)
+
+
+def _logreg_record(rounds: int) -> dict:
+    from repro.fl import run_hier_simulation
+    from repro.models.logistic import logistic_apply, logistic_loss
+
+    ds, params, cfg, topo = logreg_fleet_problem()
     runs = {}
     for engine in ("fused", "streamed"):
         runs[engine] = run_hier_simulation(
@@ -160,16 +171,13 @@ def _logreg_record(rounds: int) -> dict:
 
 
 def _transformer_record(quick: bool) -> dict:
-    if quick:
-        d_model, vocab, layers = 256, 2048, 4      # ≈ 3.7M params (CI-sized)
-    else:
-        d_model, vocab, layers = 1024, 8192, 4     # ≈ 58.7M params
-    deltas, grads, template, n = _transformer_stacked(d_model, vocab, layers,
-                                                      P_ROUND, seed=1)
+    d_model, vocab, layers = QUICK_SHAPE if quick else FULL_SHAPE
+    deltas, grads, template, n = transformer_stacked(d_model, vocab, layers,
+                                                     P_ROUND, seed=1)
     cfg = SolveConfig(beta=5.0, ridge=1e-6)
-    cohorts = _cohorts(P_ROUND, GATEWAYS)
+    groups = cohorts(P_ROUND, GATEWAYS)
     seng = StreamedRoundEngine(template, cfg, "contextual", chunk=CHUNK)
-    secs = _time_rounds(seng, template, deltas, grads, cohorts,
+    secs = _time_rounds(seng, template, deltas, grads, groups,
                         reps=2 if quick else 3)
     peak = seng.peak_round_bytes(P_ROUND)
     dense = dense_round_bytes(P_ROUND, n)
@@ -185,10 +193,10 @@ def _transformer_record(quick: bool) -> dict:
     if quick:
         # CI-sized: the dense engine still fits — diff the round deltas
         feng = HierRoundEngine(template, cfg, "contextual")
-        ctx, sdelta, _, _ = _round_once(seng, template, deltas, grads,
-                                        cohorts)
-        _, fdelta, _, _ = _round_once(feng, template, deltas, grads,
-                                      cohorts)
+        ctx, sdelta, _, _ = round_once(seng, template, deltas, grads,
+                                       groups)
+        _, fdelta, _, _ = round_once(feng, template, deltas, grads,
+                                     groups)
         rec["delta_max_abs_err"] = float(jnp.max(jnp.abs(
             ctx.materialize(sdelta) - fdelta)))
     return rec

@@ -3,7 +3,9 @@ tracker hop that makes every bench's JSON a projection of its event trace.
 """
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 from typing import Callable, Dict, List
 
 import jax
@@ -18,6 +20,17 @@ from repro.models.logistic import logistic_apply, logistic_loss
 from repro.obs import current_tracker
 
 ROWS: List[str] = []
+REPO_ROOT = Path(__file__).resolve().parents[1]
+
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compile cache at one fixed path inside the
+    checkout, so every later run from it finds what earlier runs compiled.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here."""
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
 
 
 def emit(name: str, us_per_call: float, derived: str) -> None:

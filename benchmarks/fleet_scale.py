@@ -46,7 +46,7 @@ DIM, CLASSES, SAMPLES = 16, 4, 16
 COHORT_CHUNK = 131_072
 
 
-def _params():
+def fleet_params():
     return get_model(ArchConfig(name="lr", family="logreg", input_dim=DIM,
                                 num_classes=CLASSES)
                      ).init(jax.random.PRNGKey(0))
@@ -61,16 +61,28 @@ def _peak_rss_mb() -> float:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
 
-def _fleet_record(n_dev: int, rounds: int, params) -> dict:
-    gws = max(4, n_dev // 500)
+def fleet_gateways(n_dev: int) -> int:
+    return max(4, n_dev // 500)
+
+
+def fleet_run(n_dev: int, rounds: int, params, *, mesh=None):
+    """One cohort-scheduled two-tier run over an ``n_dev`` virtual fleet;
+    ``mesh`` (e.g. ``repro.sharding.specs.fleet_mesh()``) shards the cohort
+    over its ``'fleet'`` axis."""
     ds = VirtualFleetDataset(num_devices=n_dev, samples_per_device=SAMPLES,
                              dim=DIM, num_classes=CLASSES, seed=3)
-    topo = stacked_two_tier(array_bimodal_fleet(n_dev), gws)
-    r = run_hier_simulation(
-        f"fleet_{n_dev}", logistic_loss, logistic_apply, params, ds, _cfg(),
-        topo, num_rounds=rounds, selection_seed=SEED, eval_every=rounds,
-        scheduler_mode="cohort", rng_stream="v2",
+    topo = stacked_two_tier(array_bimodal_fleet(n_dev), fleet_gateways(n_dev))
+    return run_hier_simulation(
+        f"fleet_{n_dev}", logistic_loss, logistic_apply, params, ds,
+        _cfg(), topo, num_rounds=rounds, selection_seed=SEED,
+        eval_every=rounds, scheduler_mode="cohort", rng_stream="v2",
+        mesh=mesh,
         cohort_chunk=COHORT_CHUNK if n_dev > COHORT_CHUNK else None)
+
+
+def _fleet_record(n_dev: int, rounds: int, params) -> dict:
+    gws = fleet_gateways(n_dev)
+    r = fleet_run(n_dev, rounds, params)
     steady = r.engine.get("steady_wall_time_per_round_s") or 0.0
     return {
         "scenario": "fleet", "fleet_size": n_dev, "num_gateways": gws,
@@ -119,7 +131,7 @@ def _equivalence_record(rounds: int, params) -> dict:
 
 def collect(rounds: int = 3, quick: bool = True) -> Dict[str, List[dict]]:
     """Run the sweep and return JSON-ready records (also used by --json)."""
-    params = _params()
+    params = fleet_params()
     records = [_equivalence_record(rounds, params)]
     for n_dev in QUICK_SIZES:
         records.append(_fleet_record(n_dev, rounds, params))

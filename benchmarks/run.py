@@ -80,7 +80,9 @@ def main() -> None:
 
     from repro.obs import JsonlTracker, use_tracker
 
-    from .common import publish_bench
+    from .common import publish_bench, use_compile_cache
+
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     wrote_json = False

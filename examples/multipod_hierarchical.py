@@ -16,14 +16,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.distributed import (contextual_combine_sharded,
                                     hierarchical_contextual_combine)
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     n = 1024           # parameter slice per example
     beta = 10.0
     key = jax.random.PRNGKey(0)
@@ -45,8 +45,8 @@ def main():
                 u, gs, beta)
             return (flat[None, None], hier[None, None],
                     alpha[None, None], a_pods[None, None])
-        return shard_map(
-            body, mesh=mesh,
+        return jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(P("pod", "data", "model"), P(None, None, "model")
                       if False else P("model")),
             out_specs=(P("pod", "data", "model"), P("pod", "data", "model"),

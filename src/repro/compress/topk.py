@@ -9,8 +9,7 @@ input, the classic EF construction that restores convergence for any
 contraction compressor.  At k = n the scheme is the identity, the exactness
 anchor the tests pin against the uncompressed pipeline.
 
-The selection itself streams through ``kernels.ops.topk_select`` (chunked
-per-block top-k + candidate merge; Pallas twin in ``kernels.topk``).
+The selection itself runs through ``kernels.ops.topk_select``.
 """
 from __future__ import annotations
 

@@ -30,6 +30,7 @@ the decodes, and the cloud's γ stage runs on sketched cross-terms.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from collections import deque
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.federated import FederatedDataset
+from ..kernels.registry import force_backend
 from ..obs import current_tracker, spans
 from .client import client_update
 from .metrics import evaluate_classifier, global_train_loss
@@ -517,7 +519,10 @@ def run_hier_simulation(name: str, loss_fn: Callable, apply_fn: Callable,
     it (params replicated, per-device rows split — see
     :func:`repro.sharding.specs.shard_cohort_fn`) and row-shards the
     streamed engine's (P, n) statistics pass
-    (:func:`repro.sharding.specs.stream_round_shardings`).
+    (:func:`repro.sharding.specs.stream_round_shardings`).  Over a mesh of
+    more than one device the round's kernel ops run on ``xla``: Pallas TPU
+    kernels cannot be partitioned automatically, and the rows they would
+    read are sharded.
 
     Fleet scale.  ``topology`` may be a :class:`repro.hier.StackedTopology`
     (array-native, no per-device nodes) and ``dataset`` a
@@ -705,7 +710,9 @@ def run_hier_simulation(name: str, loss_fn: Callable, apply_fn: Callable,
     result.gamma_history = _history_buffer(record_history)
     round_walls: List[float] = []
     t0 = time.time()
-    with spans.use_virtual_clock(lambda: scheduler.now):
+    kernels = (force_backend("xla") if mesh is not None and mesh.size > 1
+               else contextlib.nullcontext())
+    with spans.use_virtual_clock(lambda: scheduler.now), kernels:
         for t in range(num_rounds):
             with spans.span("round", round=t):
                 round_t0 = time.perf_counter()

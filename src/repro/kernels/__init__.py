@@ -1,7 +1,7 @@
 """Backend-aware kernel engine for the paper's compute hot spots.
 
-Ops (each with ``pallas`` / ``xla`` / ``ref`` backends, autotune-dispatched
-through :mod:`repro.kernels.registry` — see ``ops.py``):
+Ops (each with ``pallas`` / ``xla`` / ``ref`` backends unless noted,
+autotune-dispatched through :mod:`repro.kernels.registry` — see ``ops.py``):
 
   * ``gram`` / ``gram_block`` — fused U Uᵀ / U g streaming contractions
     (server + hierarchical-merge aggregation)
@@ -9,9 +9,9 @@ through :mod:`repro.kernels.registry` — see ``ops.py``):
   * ``sketch``      — fused stacked sketch-apply U Rᵀ (explicit matrix)
   * ``sign_sketch`` — counter-based RNG sign sketch; R generated in-kernel,
     never materialized (``rng_sketch.py``)
-  * ``topk``        — chunked top-k magnitude selection
-  * ``decode_attn`` — flash-decode attention with LSE partials for
-    seq-sharded KV caches (legacy dispatch, serving path)
+  * ``topk``        — top-k magnitude selection (``xla`` / ``ref`` only)
+  * ``flash_decode`` — flash-decode attention with LSE partials for
+    seq-sharded KV caches (the serving path)
 
 Pallas kernels are validated on CPU with ``interpret=True`` against the
 ``ref.py`` oracles and compile for real on TPU; off-TPU the autotuner picks

@@ -12,6 +12,7 @@ on_tpu()`` defaults are gone.
 Back-compat forcing: ``use_pallas=True`` pins the Pallas path (interpret
 off-TPU — the end-to-end kernel validation tests), ``use_pallas=False`` pins
 the reference oracle; ``backend=`` names any registered backend directly.
+``topk`` and ``sign_sketch_adjoint`` have no Pallas backend.
 """
 from __future__ import annotations
 
@@ -29,8 +30,7 @@ from .registry import (backends, dispatch, force_backend, on_tpu,
 from .rng_sketch import rng_sketch_pallas, rng_sketch_xla, \
     rng_sketch_adjoint_xla
 from .sketch import sketch_apply_pallas
-from .stream import stream_stats_pallas, stream_stats_xla
-from .topk import topk_select_pallas
+from .stream import pallas_tile, stream_stats_pallas, stream_stats_xla
 
 __all__ = ["on_tpu", "gram_and_cross", "gram_block_and_cross",
            "stream_stats", "sketch_apply", "topk_select",
@@ -61,10 +61,22 @@ def _backend_for(use_pallas: Optional[bool],
 
 # --------------------------------------------------------------- gram ops
 
+# VMEM the gram kernel's double-buffered (K, block_n) input block plus its
+# resident (K, K) output may take: the v5e compiler refuses K=2000 at
+# block_n=2048 (≈49 MB) and accepts K=1800 (≈42 MB)
+GRAM_VMEM_BYTES = 32 << 20
+
+
+def _gram_pallas_ok(u, g, block_n=2048) -> bool:
+    Kp = u.shape[0] + (-u.shape[0]) % 8
+    return (Kp * Kp * 4 + 2 * Kp * block_n * u.dtype.itemsize
+            <= GRAM_VMEM_BYTES)
+
+
 register_impl("gram", "pallas",
               lambda u, g, block_n=2048: gram_pallas(
                   u, g, block_n=block_n, interpret=not on_tpu()),
-              eligible=_not_interpret)
+              supports=_gram_pallas_ok, eligible=_not_interpret)
 _gram_xla_jit = jax.jit(ref.gram_ref)
 register_impl("gram", "xla",
               lambda u, g, block_n=2048: _gram_xla_jit(u, g))
@@ -108,13 +120,16 @@ def _same_2d(d, g, block_n=0) -> bool:
 
 
 def _stream_pallas_ok(d, g, block_n=2048) -> bool:
-    # the pallas wrapper pads to (8-row, block_n-column) tiles with jnp.pad
+    # the pallas wrapper pads to (8-row, tile-column) tiles with jnp.pad
     # — an O(P·n) input copy that would break the streamed engine's
     # O(P·chunk) memory model, so dispatch/autotune only offer it on
     # already-aligned shapes (explicit backend="pallas" still runs the
     # padded path for validation)
-    return (_same_2d(d, g, block_n) and d.shape[0] % 8 == 0
-            and d.shape[1] % block_n == 0)
+    if not _same_2d(d, g):
+        return False
+    P, n = d.shape
+    tile = pallas_tile(P, jnp.promote_types(d.dtype, g.dtype), block_n)
+    return P % 8 == 0 and n % tile == 0
 
 
 register_impl("stream_stats", "pallas",
@@ -172,34 +187,17 @@ def sketch_apply(updates: jax.Array, sketch: jax.Array, *,
                     backend=_backend_for(use_pallas, backend))
 
 
-register_impl("topk", "pallas",
-              lambda v, k, block_n=2048: topk_select_pallas(
-                  v, k, block_n=block_n, interpret=not on_tpu()),
-              supports=lambda v, k, block_n=2048: k <= block_n,
-              eligible=_not_interpret)
+# no pallas backend: the TPU kernel compiler lowers neither lax.top_k nor
+# the gather a chunked selection needs
 _topk_xla_jit = jax.jit(ref.topk_ref, static_argnums=1)
-register_impl("topk", "xla",
-              lambda v, k, block_n=2048: _topk_xla_jit(v, k))
-register_impl("topk", "ref",
-              lambda v, k, block_n=2048: ref.topk_ref(v, k))
+register_impl("topk", "xla", lambda v, k: _topk_xla_jit(v, k))
+register_impl("topk", "ref", lambda v, k: ref.topk_ref(v, k))
 
 
-def topk_select(vec: jax.Array, k: int, *,
-                use_pallas: Optional[bool] = None,
-                block_n: int = 2048,
-                backend: Optional[str] = None
+def topk_select(vec: jax.Array, k: int, *, backend: Optional[str] = None
                 ) -> Tuple[jax.Array, jax.Array]:
-    """k largest-|v| entries as (values, indices i32).
-
-    ``use_pallas=True`` keeps the PR-3 semantics: it silently falls back to
-    the autotuned path when k exceeds the kernel's per-chunk candidate
-    budget ``block_n`` (the op's ``supports`` constraint — forced backends
-    via ``force_backend``/env fall back the same way).  An explicit
-    ``backend="pallas"`` is a hard requirement and raises instead."""
-    be = _backend_for(use_pallas, backend)
-    if backend is None and be == "pallas" and k > block_n:
-        be = None                     # legacy silent fallback (tested)
-    return dispatch("topk", vec, k, block_n=block_n, backend=be)
+    """k largest-|v| entries as (values, indices i32)."""
+    return dispatch("topk", vec, k, backend=backend)
 
 
 # ----------------------------------------------------------- combine / rng

@@ -35,7 +35,7 @@ def sketch_ref(updates: jax.Array, sketch: jax.Array) -> jax.Array:
 
 def topk_ref(vec: jax.Array, k: int):
     """(values, indices i32) of the k largest-|v| entries — oracle for
-    kernels.topk."""
+    the ``topk`` op."""
     v = vec.astype(jnp.float32)
     _, idx = jax.lax.top_k(jnp.abs(v), k)
     return jnp.take(v, idx), idx.astype(jnp.int32)

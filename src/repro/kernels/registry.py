@@ -33,9 +33,12 @@ Forcing a backend (tests, debugging, benchmarks):
 
 Calls made under a jit trace cannot time anything, so tracer arguments fall
 back to the cached winner for the bucket, or a static preference order
-(pallas on TPU, else xla) when the bucket was never tuned.  Fused round
-engines instead pick eagerly at build time via :func:`select_impl` and close
-over the winning implementation.
+(pallas on TPU, else xla) when the bucket was never tuned; those picks are
+listed by :func:`static_picks`.  Fused round engines instead pick eagerly at
+build time via :func:`select_impl` and close over the winning
+implementation.  A candidate that fails while it is timed fails the call:
+on TPU that is a Pallas kernel the compiler refused, which must surface
+rather than lose the timing to ``xla`` unseen.
 """
 from __future__ import annotations
 
@@ -67,7 +70,7 @@ class KernelImpl:
     backend: str
     fn: Callable
     # supports(*args, **kw) -> bool: shape/parameter constraints (e.g. the
-    # chunked top-k kernel needs k <= block_n)
+    # stream_stats kernel only takes tile-aligned widths)
     supports: Optional[Callable[..., bool]] = None
     # eligible() -> bool: platform gate for *autotuning* (interpret-mode
     # Pallas off-TPU is never a candidate; forcing bypasses this)
@@ -90,6 +93,7 @@ class AutotuneEntry:
 
 _IMPLS: Dict[str, Dict[str, KernelImpl]] = {}
 _CACHE: Dict[Tuple, AutotuneEntry] = {}
+_STATIC: Dict[Tuple, str] = {}     # (op, bucket) -> backend picked untimed
 _FORCED: List[Tuple[Optional[str], str]] = []   # (op or None, backend) stack
 _EMITTED: set = set()      # (op, bucket, backend, forced) already streamed
 
@@ -139,8 +143,9 @@ class force_backend:
     """Context manager pinning dispatch to one backend (optionally one op).
 
     Forcing is a *preference*: a forced backend whose ``supports`` check
-    rejects the call's shapes (e.g. the chunked top-k kernel with
-    ``k > block_n``) falls back to normal selection instead of crashing.
+    rejects the call's shapes (e.g. the stream_stats kernel on a width that
+    is not tile-aligned) falls back to normal selection instead of
+    crashing.
     To hard-require a backend, pass ``backend=`` at the call site — that
     path runs the implementation unconditionally and lets it raise."""
 
@@ -184,15 +189,8 @@ def _bucket(args: Tuple, kw: Dict) -> Tuple:
     return tuple(parts)
 
 
-# jax.core.Tracer moved across jax versions; fall back to duck typing
-_TRACER = getattr(jax.core, "Tracer", None)
-
-
 def _has_tracer(args: Tuple) -> bool:
-    if _TRACER is not None:
-        return any(isinstance(a, _TRACER) for a in args)
-    return any(isinstance(a, jax.Array) and hasattr(a, "_trace")
-               for a in args)
+    return any(isinstance(a, jax.core.Tracer) for a in args)
 
 
 def _time_impl(impl: KernelImpl, args: Tuple, kw: Dict) -> float:
@@ -224,17 +222,13 @@ def _autotune(op: str, bucket: Tuple, args: Tuple, kw: Dict) -> AutotuneEntry:
         # cost inside a round's first stage is attributable per backend
         with spans.span("autotune", op=op, bucket=repr(bucket)):
             for impl in cands:
-                try:
-                    with spans.span("candidate", op=op,
-                                    backend=impl.backend) as h:
-                        us = _time_impl(impl, args, kw)
-                        if h is not None:
-                            h.tags["us_per_call"] = us
-                    entry.timings_us[impl.backend] = us
-                except Exception:       # a candidate that crashes never wins
-                    continue
-        if entry.timings_us:
-            entry.backend = min(entry.timings_us, key=entry.timings_us.get)
+                with spans.span("candidate", op=op,
+                                backend=impl.backend) as h:
+                    us = _time_impl(impl, args, kw)
+                    if h is not None:
+                        h.tags["us_per_call"] = us
+                entry.timings_us[impl.backend] = us
+        entry.backend = min(entry.timings_us, key=entry.timings_us.get)
     _CACHE[(op, bucket)] = entry
     _emit_decision(op, bucket, entry.backend, entry.timings_us, forced=False)
     return entry
@@ -267,8 +261,10 @@ def select_impl(op: str, *args: Any, **kw: Any) -> KernelImpl:
             for name in _STATIC_ORDER:
                 impl = _IMPLS[op].get(name)
                 if impl and impl.is_eligible() and impl.ok_for(*args, **kw):
+                    _STATIC[(op, bucket)] = name
                     return impl
-            return next(iter(_IMPLS[op].values()))
+            raise RuntimeError(f"no eligible backend for kernel op '{op}' "
+                               f"(registered: {backends(op)})")
         entry = _autotune(op, bucket, args, kw)
     impl = _IMPLS[op].get(entry.backend)
     if impl is None or not impl.ok_for(*args, **kw):
@@ -344,6 +340,15 @@ def autotune_records() -> List[Dict[str, Any]]:
     return records
 
 
+def static_picks() -> List[Dict[str, Any]]:
+    """The untimed picks made under a jit trace (one record per
+    (op, bucket), in the order they were made): the backend the static
+    preference order resolved to."""
+    return [{"op": op, "bucket": repr(bucket), "backend_selected": backend}
+            for (op, bucket), backend in _STATIC.items()]
+
+
 def clear_autotune_cache() -> None:
     _CACHE.clear()
+    _STATIC.clear()
     _EMITTED.clear()

@@ -54,7 +54,8 @@ def sign_tile(seed: jax.Array, row0, col0, rows: int, cols: int) -> jax.Array:
     r = r + jnp.asarray(row0, jnp.uint32)
     c = c + jnp.asarray(col0, jnp.uint32)
     h = _mix32(c ^ _mix32(r ^ jnp.asarray(seed, jnp.uint32)))
-    return 1.0 - 2.0 * (h >> 31).astype(jnp.float32)
+    # via int32: the TPU kernel compiler has no uint32 -> float32 cast
+    return 1.0 - 2.0 * (h >> 31).astype(jnp.int32).astype(jnp.float32)
 
 
 def rng_sign_matrix(seed, m: int, n: int) -> jax.Array:

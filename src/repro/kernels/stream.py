@@ -21,11 +21,12 @@ Both streaming implementations keep the working set at O(P·block_n):
     that copy is exactly what transformer-width rounds cannot afford), plus
     one statically-sliced remainder tile: no masking, no window ever pays
     more than its own bandwidth.
-  * :func:`stream_stats_pallas` — grid over column tiles, both (P, block_n)
+  * :func:`stream_stats_pallas` — grid over column tiles, both (P, tile)
     operand tiles ride one HBM→VMEM stream, outputs accumulate in VMEM f32
-    across the grid (constant index_map).  Inputs are padded to the tile
-    boundary like the other Pallas kernels — compiled on TPU only, where
-    the pad is a device-side copy the VMEM budget tolerates.
+    across the grid (constant index_map).  The tile is the caller's
+    ``block_n`` capped by :func:`pallas_tile` to what fits VMEM, so the
+    streamed engine's column chunk never becomes the kernel's block.
+    Inputs are padded to the tile boundary like the other Pallas kernels.
 
 Inputs may be any float dtype (bf16 transformer updates upcast per tile);
 accumulation is always f32.  The eager oracle lives in ``kernels.ref``
@@ -39,6 +40,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+# bytes one (P, tile) input block may hold in VMEM.  Both inputs are
+# double-buffered and upcast to f32 in the kernel; at 2 MiB per block the
+# v5e compiler accepts the kernel, while a 2**18-column bf16 block at P=16
+# (8 MiB) runs out of VMEM.
+PALLAS_TILE_BYTES = 1 << 21
+
+
+def pallas_tile(P: int, dtype, block_n: int) -> int:
+    """Column tile of :func:`stream_stats_pallas` for a ``block_n`` chunk
+    over (P, n) inputs of ``dtype``: ``block_n`` capped to the largest
+    power of two whose sublane-padded (P, tile) block fits
+    ``PALLAS_TILE_BYTES`` (never below one 128-lane tile)."""
+    row_bytes = (P + (-P) % 8) * jnp.dtype(dtype).itemsize
+    fit = max(PALLAS_TILE_BYTES // row_bytes, 1)
+    cap = max(128, 1 << (fit.bit_length() - 1))
+    return min(int(block_n), cap)
 
 
 def _accum_tile(G, C, d, g):
@@ -101,13 +120,16 @@ def _stream_stats_kernel(d_ref, g_ref, G_ref, C_ref):
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def stream_stats_pallas(deltas: jax.Array, grads: jax.Array, *,
                         block_n: int = 2048, interpret: bool = True):
-    """Pallas twin: grid over column tiles, (G, C) resident in VMEM f32.
-    P is padded to the 8-sublane boundary, n to a ``block_n`` multiple
-    (zero columns contribute nothing to either product)."""
+    """Pallas twin: grid over column tiles of :func:`pallas_tile` width,
+    (G, C) resident in VMEM f32.  P is padded to the 8-sublane boundary,
+    n to a tile multiple (zero columns contribute nothing to either
+    product)."""
     P, n = deltas.shape
     if grads.shape != deltas.shape:
         raise ValueError(f"deltas/grads disagree: {deltas.shape} vs "
                          f"{grads.shape}")
+    block_n = pallas_tile(P, jnp.promote_types(deltas.dtype, grads.dtype),
+                          block_n)
     padP, padN = (-P) % 8, (-n) % block_n
     d = jnp.pad(deltas, ((0, padP), (0, padN)))
     g = jnp.pad(grads, ((0, padP), (0, padN)))

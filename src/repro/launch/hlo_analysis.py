@@ -31,12 +31,9 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalised across jax versions: 0.4.x
-    returns a list with one dict per program, newer jax a single dict."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 _OP_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^)]*\)|[a-z0-9]+\[[^\]]*\][^ ]*)\s+"
     r"(" + "|".join(_COLLECTIVES) + r")[-\w]*\(")

@@ -9,18 +9,6 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kwargs(num_axes: int) -> dict:
-    """``axis_types`` for :func:`jax.make_mesh`, empty on jax 0.4.x.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg) only exist from
-    jax 0.5; on 0.4.x every axis is implicitly Auto, so omitting the kwarg is
-    semantically identical."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e pod slice: 16×16 = 256 chips per pod; 2 pods = 512 chips.
 
@@ -38,11 +26,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"production mesh needs {need} devices, have {len(devices)} — "
             "run through launch/dryrun.py (it forces 512 host devices)")
     return jax.make_mesh(shape, axes, devices=devices[:need],
-                         **_axis_types_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist, as a 1×N ('data','model') mesh — used by CPU
     integration tests and the quickstart example."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"), **_axis_types_kwargs(2))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -28,20 +28,41 @@ from .mesh import make_host_mesh
 ENGINE_FAMILIES = ("dense", "moe")
 
 
-def _engine_serve(cfg, bundle, args) -> None:
-    params = bundle.init(jax.random.PRNGKey(0))
-    bus = ModelBus(params)
-    max_seq = args.prompt_len + args.new_tokens
-    eng = DecodeEngine(cfg, bus, num_slots=args.slots, max_seq=max_seq,
-                       scan_chunk=args.scan_chunk, greedy=args.greedy)
-    rng = np.random.default_rng(1)
-    for _ in range(args.requests):
-        eng.submit([int(t) for t in rng.integers(0, cfg.vocab_size,
-                                                 args.prompt_len)],
-                   max_new=args.new_tokens)
-    t0 = time.time()
+def build_engine(cfg, bundle, *, slots: int, max_seq: int,
+                 scan_chunk: int = 8, greedy: bool = True) -> DecodeEngine:
+    """Random params (seed 0) published on a :class:`ModelBus`, served by
+    a :class:`DecodeEngine`.  The init is jitted so the full-width f32
+    random draws fuse into their low-precision outputs instead of living
+    on the device as eager temporaries."""
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(0))
+    return DecodeEngine(cfg, ModelBus(params), num_slots=slots,
+                        max_seq=max_seq, scan_chunk=scan_chunk, greedy=greedy)
+
+
+def serve_random_prompts(eng: DecodeEngine, *, requests: int,
+                         prompt_len: int, new_tokens: int, seed: int = 1):
+    """Submit ``requests`` random prompts and run the engine until it
+    drains.  Returns ``({rid: prompt}, completions, wall seconds)``; the
+    engine reads every chunk's tokens back to the host, so the time covers
+    the device work."""
+    rng = np.random.default_rng(seed)
+    prompts = {}
+    for _ in range(requests):
+        prompt = [int(t) for t in rng.integers(0, eng.cfg.vocab_size,
+                                               prompt_len)]
+        prompts[eng.submit(prompt, max_new=new_tokens)] = prompt
+    t0 = time.perf_counter()
     done = eng.run()
-    dt = time.time() - t0
+    return prompts, done, time.perf_counter() - t0
+
+
+def _engine_serve(cfg, bundle, args) -> None:
+    eng = build_engine(cfg, bundle, slots=args.slots,
+                       max_seq=args.prompt_len + args.new_tokens,
+                       scan_chunk=args.scan_chunk, greedy=args.greedy)
+    _, done, dt = serve_random_prompts(eng, requests=args.requests,
+                                       prompt_len=args.prompt_len,
+                                       new_tokens=args.new_tokens)
     total = sum(len(c.tokens) for c in done)
     print(f"arch={cfg.name} slots={args.slots} requests={args.requests} "
           f"prompt={args.prompt_len} new={args.new_tokens} "

@@ -220,14 +220,13 @@ def shard_cohort_fn(mesh: Mesh, cohort_fn, num_stacked_args: int):
     device trains its own block of the cohort.  Cohorts that don't divide
     the axis are padded (first row repeated) and sliced back, so any P
     works.  Returns a jitted callable."""
-    from jax.experimental.shard_map import shard_map
     import jax.numpy as jnp
 
     axis = mesh.shape["fleet"]
-    inner = shard_map(
+    inner = jax.shard_map(
         cohort_fn, mesh=mesh,
         in_specs=(P(),) + (P("fleet"),) * num_stacked_args,
-        out_specs=P("fleet"), check_rep=False)
+        out_specs=P("fleet"), check_vma=False)
 
     @jax.jit
     def wrapped(params, *args):

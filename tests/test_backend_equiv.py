@@ -46,7 +46,7 @@ CALLS = {
         d[0], d[0][:3], d[1], backend=be, block_n=128),
     "sketch": lambda d, be: ops.sketch_apply(d[0], d[2], backend=be,
                                              block_n=128),
-    "topk": lambda d, be: ops.topk_select(d[1], 17, backend=be, block_n=128),
+    "topk": lambda d, be: ops.topk_select(d[1], 17, backend=be),
     "combine": lambda d, be: ops.weighted_combine(d[3], d[0], d[4],
                                                   backend=be, block_n=128),
     "sign_sketch": lambda d, be: ops.sign_sketch(d[0], 1234, 11, backend=be,
@@ -72,10 +72,12 @@ def test_every_backend_matches_ref(op):
 
 
 def test_every_op_has_all_three_backends():
-    for op in ("gram", "gram_block", "sketch", "topk", "combine",
+    for op in ("gram", "gram_block", "sketch", "combine", "stream_stats",
                "sign_sketch", "flash_decode"):
         assert {"pallas", "xla", "ref"} <= set(ops.backends(op)), op
-    assert {"xla", "ref"} <= set(ops.backends("sign_sketch_adjoint"))
+    # no TPU kernel (topk: Mosaic lowers neither lax.top_k nor the gather)
+    for op in ("topk", "sign_sketch_adjoint"):
+        assert set(ops.backends(op)) == {"xla", "ref"}, op
 
 
 # ------------------------------------------------------- decode attention
@@ -261,13 +263,18 @@ def test_force_backend_scoped_and_use_pallas_compat():
 
 def test_forced_backend_is_preference_explicit_backend_is_requirement():
     """force_backend/env forcing falls back when supports() rejects the
-    shapes; an explicit backend= arg is a hard requirement and raises."""
-    v = jax.random.normal(jax.random.PRNGKey(0), (6000,))
+    shapes; an explicit backend= arg is a hard requirement: it runs that
+    implementation unconditionally and lets it raise."""
+    d = jax.random.normal(jax.random.PRNGKey(0), (8, 300))   # not 128-aligned
+    want = ref.stream_stats_ref(d, d)
     with registry.force_backend("pallas"):
-        vals, _ = ops.topk_select(v, 3000, block_n=128)   # k > block_n
-        assert vals.shape == (3000,)                      # fell back
-    with pytest.raises(ValueError, match="exceeds block_n"):
-        ops.topk_select(v, 3000, backend="pallas", block_n=128)
+        impl = registry.select_impl("stream_stats", d, d, block_n=128)
+        assert impl.backend != "pallas"                   # fell back
+        _allclose(ops.stream_stats(d, d, block_n=128), want)
+    # explicit: the padded pallas path runs although supports() rejects it
+    _allclose(ops.stream_stats(d, d, backend="pallas", block_n=128), want)
+    with pytest.raises(ValueError, match="disagree"):
+        ops.stream_stats(d, d[:, :200], backend="pallas", block_n=128)
 
 
 def test_fused_stage_cache_rebinds_under_forced_backend():

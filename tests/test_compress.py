@@ -1,7 +1,7 @@
 """Tests for the summary-compression subsystem (repro.compress): round-trip
 and exactness anchors per scheme, EF telescoping, contraction of the shrunk
-sketch decodes, sketch-space Gram correctness, the Pallas sketch/top-k
-kernels against their oracles, ledger byte accounting == serialized payload
+sketch decodes, sketch-space Gram correctness, the Pallas sketch kernel
+against its oracle, ledger byte accounting == serialized payload
 sizes, the §III-C gateway-tier pool correction, and the compressed
 hierarchical simulation end to end (including exact recovery at k = n)."""
 import jax
@@ -21,7 +21,6 @@ from repro.hier import (HierConfig, compressed_summary_bytes, star_topology,
                         summarize_updates, two_tier_topology)
 from repro.kernels import ops
 from repro.kernels.sketch import sketch_apply_pallas
-from repro.kernels.topk import topk_select_pallas
 
 import repro.hier.hier_server  # noqa: F401  (registers hier aggregators)
 
@@ -245,29 +244,6 @@ def test_sketch_kernel_matches_ref():
         sketch_apply_pallas(U, R[:, :100], interpret=True)
 
 
-@pytest.mark.parametrize("n,k,block_n", [(333, 7, 128), (500, 40, 128),
-                                         (128, 128, 128), (1000, 3, 256)])
-def test_topk_kernel_matches_ref(n, k, block_n):
-    v = jax.random.normal(jax.random.PRNGKey(n + k), (n,))
-    vals_p, idx_p = topk_select_pallas(v, k, block_n=block_n, interpret=True)
-    vals_r, idx_r = ops.topk_select(v, k, use_pallas=False)
-    # compare as reconstructed sparse vectors (robust to tie ordering)
-    dense_p = np.zeros(n); dense_p[np.asarray(idx_p)] = np.asarray(vals_p)
-    dense_r = np.zeros(n); dense_r[np.asarray(idx_r)] = np.asarray(vals_r)
-    np.testing.assert_allclose(dense_p, dense_r, atol=1e-6)
-    assert idx_p.dtype == jnp.int32 and int(idx_p.max()) < n
-    # padded chunks never leak pad indices
-    assert len(set(np.asarray(idx_p).tolist())) == k
-
-
-def test_topk_kernel_rejects_oversized_k_and_ops_falls_back():
-    v = jax.random.normal(jax.random.PRNGKey(0), (600,))
-    with pytest.raises(ValueError, match="exceeds block_n"):
-        topk_select_pallas(v, 300, block_n=128, interpret=True)
-    vals, idx = ops.topk_select(v, 300, use_pallas=True, block_n=128)
-    assert vals.shape == (300,)                  # silently used the oracle
-
-
 # ---------------------------------------------------------------------------
 # §III-C pool pricing at the gateway tier
 # ---------------------------------------------------------------------------
@@ -461,25 +437,5 @@ def test_wire_floats_matches_serialization_property():
         comp = c.encode(v, seed=seed)
         assert comp.nbytes == pytest.approx(4.0 * c.wire_floats(n))
         assert c.decode(comp).shape == (n,)
-
-    check()
-
-
-def test_topk_kernel_property():
-    hyp = pytest.importorskip("hypothesis")
-    from hypothesis import given, settings, strategies as st
-
-    @settings(max_examples=15, deadline=None)
-    @given(n=st.integers(10, 700), k=st.integers(1, 64),
-           seed=st.integers(0, 999))
-    def check(n, k, seed):
-        k = min(k, n)
-        v = jax.random.normal(jax.random.PRNGKey(seed), (n,))
-        vals_p, idx_p = topk_select_pallas(v, k, block_n=128, interpret=True)
-        vals_r, idx_r = ops.topk_select(v, k, use_pallas=False)
-        np.testing.assert_allclose(
-            np.sort(np.abs(np.asarray(vals_p))),
-            np.sort(np.abs(np.asarray(vals_r))), atol=1e-6)
-        assert int(idx_p.max()) < n
 
     check()

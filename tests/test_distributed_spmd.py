@@ -35,11 +35,11 @@ def test_sharded_contextual_combine_matches_reference():
         import json
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core.distributed import contextual_combine_sharded
         from repro.core import gram_and_cross, solve_alpha_simple
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         K, n, beta = 4, 64, 8.0
         key = jax.random.PRNGKey(0)
         U = jax.random.normal(key, (K, n), jnp.float32)
@@ -49,9 +49,10 @@ def test_sharded_contextual_combine_matches_reference():
             comb, alpha = contextual_combine_sharded(u[0], gs, beta, 1e-6)
             return comb[None], alpha[None]
 
-        comb, alpha = jax.jit(shard_map(
+        comb, alpha = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("data", "model"), P("model")),
-            out_specs=(P("data", "model"), P("data", None))))(U, g)
+            out_specs=(P("data", "model"), P("data", None)),
+            check_vma=False))(U, g)
 
         G, c = gram_and_cross(U, g)
         alpha_ref = solve_alpha_simple(G, c, beta, 1e-6)
@@ -83,7 +84,8 @@ def test_spmd_train_step_contextual_vs_singlehost():
             num_layers=1, d_model=64, d_ff=128, vocab_size=128,
             num_heads=2, num_kv_heads=2, head_dim=32)
         bundle = get_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = InputShape("t", "train", 16, 8)
         step = build_train_step(cfg, mesh, shape, aggregator="contextual",
                                 lr=0.05, remat=False)

@@ -380,6 +380,62 @@ def test_fleet_axis_shard_map_parity():
     assert result["v_spec"] == "PartitionSpec('fleet',)"
 
 
+_MESH_KERNEL_SCRIPT = textwrap.dedent("""
+    import json
+    import jax
+    from repro.data.fleetgen import VirtualFleetDataset
+    from repro.edge import array_bimodal_fleet
+    from repro.fl import run_hier_simulation
+    from repro.hier import HierConfig, stacked_two_tier
+    from repro.kernels import registry
+    from repro.models import get_model
+    from repro.models.config import ArchConfig
+    from repro.models.logistic import logistic_apply, logistic_loss
+    from repro.obs import InMemoryTracker, use_tracker
+    from repro.sharding.specs import fleet_mesh
+
+    ds = VirtualFleetDataset(num_devices=64, samples_per_device=16, dim=8,
+                             num_classes=3, seed=3)
+    params = get_model(ArchConfig(name="lr", family="logreg", input_dim=8,
+                                  num_classes=3)).init(jax.random.PRNGKey(0))
+    cfg = HierConfig(aggregator="hier_contextual", lr=0.1, batch_size=8,
+                     min_epochs=1, max_epochs=1)
+    topo = stacked_two_tier(array_bimodal_fleet(64), 4)
+    picks = {}
+    # pallas everywhere the caller can reach; the sharded run must still
+    # keep its round kernels off it
+    with registry.force_backend("pallas"):
+        for name, mesh in (("sharded", fleet_mesh()), ("single", None)):
+            mem = InMemoryTracker()
+            with use_tracker(mem):
+                run_hier_simulation(
+                    name, logistic_loss, logistic_apply, params, ds, cfg,
+                    topo, num_rounds=1, eval_every=1,
+                    scheduler_mode="cohort", rng_stream="v2", mesh=mesh)
+            picks[name] = sorted({e.metrics["kernels/autotune/backend"]
+                                  for e in mem.metrics_events()
+                                  if "kernels/autotune/op" in e.metrics})
+    print(json.dumps(picks))
+""")
+
+
+def test_multi_device_mesh_keeps_round_kernels_off_pallas():
+    """Pallas TPU kernels cannot be partitioned over a mesh: a round whose
+    cohort rows are sharded over several devices runs its kernel ops on
+    xla, even where the caller forced pallas."""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", _MESH_KERNEL_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr
+    picks = json.loads(out.stdout.strip().splitlines()[-1])
+    assert picks == {"sharded": ["xla"], "single": ["pallas"]}
+
+
 def test_stream_round_shardings_backcompat_without_fleet_axis():
     from jax.sharding import Mesh
     from repro.sharding.specs import (stream_column_shardings,

@@ -417,6 +417,7 @@ def test_autotune_cap_preserves_alignment_residue(monkeypatch):
     class Spec:
         def __init__(self, shape):
             self.shape, self.ndim = shape, len(shape)
+            self.dtype = jnp.float32
     # unaligned true width stays ineligible for the padded pallas path
     assert not _stream_pallas_ok(Spec((8, 37)), Spec((8, 37)), block_n=8)
     assert _stream_pallas_ok(Spec((8, 40)), Spec((8, 40)), block_n=8)
