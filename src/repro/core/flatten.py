@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Callable, Iterable, List, Sequence
 
 import jax
@@ -118,6 +119,14 @@ class LeafSlab:
     matrix: jax.Array     # (K, width) view of the stacked leaf
 
 
+@partial(jax.jit, static_argnums=1)
+def flat_view_slab(leaf: jax.Array, width: int) -> jax.Array:
+    """``leaf.reshape(K, width)``: one program per leaf shape, compiled
+    under this name so that a device trace can tell the view's relayout
+    copies from any other reshape."""
+    return jnp.reshape(leaf, (leaf.shape[0], width))
+
+
 class ChunkedFlatView:
     """Leaf-aligned column-chunk view of a *stacked* pytree (leading K axis
     per leaf) — the streaming alternative to the full ``jnp.concatenate``
@@ -148,9 +157,14 @@ class ChunkedFlatView:
         offset = 0
         for i, (leaf, keep) in enumerate(zip(leaves, kept)):
             width = leaf.size // self.K
+            # a leaf already (K, width) is its own view: no program runs
+            # (as ``jnp.reshape`` returns such an array itself)
+            same = (isinstance(leaf, jax.Array)
+                    and leaf.shape == (self.K, width))
+            matrix = leaf if same else flat_view_slab(leaf, width)
             self.slabs.append(LeafSlab(
                 index=i, offset=offset, width=width, in_scope=bool(keep),
-                matrix=jnp.reshape(leaf, (self.K, width))))
+                matrix=matrix))
             offset += width
         self.n = offset
 

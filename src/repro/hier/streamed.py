@@ -55,7 +55,7 @@ import numpy as np
 from ..core.flatten import ChunkedFlatView, mix_rows
 from ..core.solve import SolveConfig, bound_value, solve_alpha
 from ..kernels.registry import force_backend, select_impl_for
-from ..obs import current_tracker, spans
+from ..obs import spans
 from . import fused as _fused
 
 Pytree = Any
@@ -132,8 +132,8 @@ def _cloud_solve_info(Gs, c, cfg):
     return gamma, info
 
 
-def tier_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
-               pool_scale: float = 1.0, robust=None) -> Callable:
+def gateway_stage_for(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
+                      pool_scale: float = 1.0, robust=None) -> Callable:
     """Device-tier stage over row indices: ``fn(G, C, idx (K,), counts,
     g_w?) -> {G, c, alpha, u_w, ghat_w, info}``.
 
@@ -154,7 +154,7 @@ def tier_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
         from ..robust.gramstats import robustify
 
     @jax.jit
-    def stage(G, C, idx, counts, g_w=None):
+    def gateway_stage(G, C, idx, counts, g_w=None):
         wts = counts / jnp.maximum(jnp.sum(counts), 1e-12)
         ghat_w = jnp.zeros((P,), jnp.float32).at[idx].set(wts)
         Gs = G[idx][:, idx]
@@ -174,12 +174,12 @@ def tier_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
         return {"G": Gs, "c": c, "alpha": alpha, "u_w": u_w,
                 "ghat_w": ghat_w, "info": info}
 
-    _STAGES[key] = stage
-    return stage
+    _STAGES[key] = gateway_stage
+    return gateway_stage
 
 
-def merge_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
-                sum_to: Optional[float] = 1.0) -> Callable:
+def merge_stage_for(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
+                    sum_to: Optional[float] = 1.0) -> Callable:
     """Parent-tier stage over child row-mixes: ``fn(G, C, W (K,P),
     GW (K,P), counts, g_w?)`` — Gram ``W G Wᵀ``, c-term ``(W C) ĝ_w``."""
     key = ("smerge", P, K, solve_cfg, mode, sum_to)
@@ -189,7 +189,7 @@ def merge_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
     cfg = _adjust(solve_cfg, sum_to=sum_to)
 
     @jax.jit
-    def stage(G, C, W, GW, counts, g_w=None):
+    def merge_stage(G, C, W, GW, counts, g_w=None):
         wts = counts / jnp.maximum(jnp.sum(counts), 1e-12)
         ghat_w = wts @ GW
         g_solve = ghat_w if g_w is None else g_w
@@ -199,12 +199,12 @@ def merge_stage(P: int, K: int, solve_cfg: SolveConfig, mode: str, *,
         return {"G": Gs, "c": c, "alpha": alpha, "u_w": alpha @ W,
                 "ghat_w": ghat_w, "info": info}
 
-    _STAGES[key] = stage
-    return stage
+    _STAGES[key] = merge_stage
+    return merge_stage
 
 
-def cloud_raw_stage(P: int, K: int, solve_cfg: SolveConfig, kind: str, *,
-                    solve_scale: float = 1.0, robust=None) -> Callable:
+def cloud_raw_stage_for(P: int, K: int, solve_cfg: SolveConfig, kind: str, *,
+                        solve_scale: float = 1.0, robust=None) -> Callable:
     """Final tier over raw device rows (star / relay): ``fn(G, C, idx,
     counts) -> {u_w, info}`` — fused ``cloud_stage``'s math on sub-blocks,
     with the same robust clip+pool hook on the cross sub-block."""
@@ -220,7 +220,7 @@ def cloud_raw_stage(P: int, K: int, solve_cfg: SolveConfig, kind: str, *,
         from ..robust.gramstats import robustify
 
     @jax.jit
-    def stage(G, C, idx, counts):
+    def cloud_raw_stage(G, C, idx, counts):
         wts = counts / jnp.maximum(jnp.sum(counts), 1e-12)
         if kind == "fedavg":
             alpha = wts
@@ -241,12 +241,12 @@ def cloud_raw_stage(P: int, K: int, solve_cfg: SolveConfig, kind: str, *,
         u_w = jnp.zeros((P,), jnp.float32).at[idx].set(alpha)
         return {"u_w": u_w, "info": info}
 
-    _STAGES[key] = stage
-    return stage
+    _STAGES[key] = cloud_raw_stage
+    return cloud_raw_stage
 
 
-def cloud_combo_stage(P: int, K: int, solve_cfg: SolveConfig,
-                      kind: str) -> Callable:
+def cloud_combo_stage_for(P: int, K: int, solve_cfg: SolveConfig,
+                          kind: str) -> Callable:
     """Final tier over child combinations: ``fn(G, C, W (K,P), g_w, counts)
     -> {eff_w, info}`` with the mass-conserving Σγ=1 solve; ``eff_w`` is
     the round's one effective row-mix ``γ @ W``."""
@@ -257,7 +257,7 @@ def cloud_combo_stage(P: int, K: int, solve_cfg: SolveConfig,
     cfg = _adjust(solve_cfg, sum_to=1.0 if kind == "combo" else None)
 
     @jax.jit
-    def stage(G, C, W, g_w, counts):
+    def cloud_combo_stage(G, C, W, g_w, counts):
         wts = counts / jnp.maximum(jnp.sum(counts), 1e-12)
         if kind == "fedavg":
             gamma = wts
@@ -268,8 +268,8 @@ def cloud_combo_stage(P: int, K: int, solve_cfg: SolveConfig,
             gamma, info = _cloud_solve_info(Gs, c, cfg)
         return {"eff_w": gamma @ W, "info": info}
 
-    _STAGES[key] = stage
-    return stage
+    _STAGES[key] = cloud_combo_stage
+    return cloud_combo_stage
 
 
 # ---------------------------------------------------------------------------
@@ -378,6 +378,8 @@ class StreamedRoundEngine:
         self._scope_key = (None if self._scope_idx is None else
                            (gram_scope, len(self._scope_idx),
                             hash(self._scope_idx.tobytes())))
+        # rounds begun so far; the next round's id, which its spans carry
+        self.rounds = 0
 
     # -- memory model --------------------------------------------------------
 
@@ -401,6 +403,12 @@ class StreamedRoundEngine:
 
     def begin_round(self, stacked_deltas: Pytree,
                     stacked_grads: Pytree) -> "StreamedRoundContext":
+        """Open round ``self.rounds`` (counted from 0): the flat views, the
+        per-slab kernel plan and the statistics pass.  Every span of the
+        round (``stream_*``, here and on the returned context) carries its
+        id as the ``round`` tag."""
+        rnd = self.rounds
+        self.rounds += 1
         if self.mesh is not None:
             from ..sharding.specs import stream_round_shardings
             stacked_deltas = jax.device_put(
@@ -409,69 +417,63 @@ class StreamedRoundEngine:
             stacked_grads = jax.device_put(
                 stacked_grads,
                 stream_round_shardings(self.mesh, stacked_grads))
-        dview = ChunkedFlatView(stacked_deltas, self.gram_scope)
-        gview = ChunkedFlatView(stacked_grads, self.gram_scope)
+        with spans.span("stream_views", round=rnd):
+            dview = ChunkedFlatView(stacked_deltas, self.gram_scope)
+            gview = ChunkedFlatView(stacked_grads, self.gram_scope)
         P = dview.K
         scoped = dview.scoped_slabs
         if scoped:
-            specs, impls, slabs_key = [], [], []
-            for s in scoped:
-                # timing cap preserves the width residue mod chunk so
-                # alignment-based supports() checks see the true shape's
-                # divisibility, and the winner at ~4M cols is the winner at
-                # full width (same memory-bound regime).  When the chunk
-                # itself exceeds the cap no capped width can stay
-                # chunk-aligned — cap hard instead of synthesizing a spec
-                # wider than the slab (which would defeat the cap's whole
-                # point: select_impl_for times dense zeros of spec size).
-                w = s.width
-                if w > AUTOTUNE_CAP_COLS:
-                    if self.chunk <= AUTOTUNE_CAP_COLS:
-                        w = min(w, (AUTOTUNE_CAP_COLS // self.chunk)
-                                * self.chunk + w % self.chunk)
-                    else:
-                        w = AUTOTUNE_CAP_COLS
-                spec = jax.ShapeDtypeStruct((P, w), s.matrix.dtype)
-                impl = select_impl_for("stream_stats", spec, spec,
-                                       block_n=self.chunk)
-                true_spec = jax.ShapeDtypeStruct((P, s.width),
-                                                 s.matrix.dtype)
-                if not impl.ok_for(true_spec, true_spec,
-                                   block_n=self.chunk):
-                    # the capped pick cannot run the real slab (e.g. the
-                    # pallas tile kernel on an unaligned width — its pad
-                    # would be the O(P·n) copy this engine exists to
-                    # avoid): take the streaming XLA path instead
-                    with force_backend("xla", op="stream_stats"):
-                        impl = select_impl_for("stream_stats", spec, spec,
-                                               block_n=self.chunk)
-                impls.append(impl)
-                slabs_key.append((P, s.width, str(s.matrix.dtype)))
-            accumulate = _accum_for(P, tuple(slabs_key), self.chunk,
-                                    tuple(impls))
+            with spans.span("stream_plan", round=rnd, slabs=len(scoped)):
+                accumulate = self._plan(P, scoped)
             # the chunked column pass: the streamed engine's per-round hot
             # spot (walks every chunk of every slab under one jit call)
             n_chunks = sum(-(-s.width // self.chunk) for s in scoped)
-            with spans.span("stream_accumulate", P=P, chunks=n_chunks,
-                            chunk_cols=self.chunk, slabs=len(scoped)):
+            with spans.span("stream_accumulate", round=rnd, P=P,
+                            chunks=n_chunks, chunk_cols=self.chunk,
+                            slabs=len(scoped)):
                 G, C = accumulate(tuple(s.matrix for s in scoped),
                                   tuple(gview.slabs[s.index].matrix
                                         for s in scoped))
         else:                       # scope matched nothing: degenerate zeros
             G = C = jnp.zeros((P, P), jnp.float32)
-        tr = current_tracker()
-        if tr.active:
-            # the streamed engine's memory story, per round: how many column
-            # chunks the accumulate pass walks and the deterministic peak
-            # working set it holds instead of the dense (P, n) matrices
-            chunks = sum(-(-s.width // self.chunk) for s in scoped)
-            tr.scope("hier/streamed").log({
-                "P": P, "chunk_cols": self.chunk, "num_chunks": chunks,
-                "num_slabs": len(scoped),
-                "peak_round_matrix_bytes": self.peak_round_bytes(P),
-                "dense_round_matrix_bytes": dense_round_bytes(P, self.n)})
         return StreamedRoundContext(self, stacked_deltas, stacked_grads,
-                                    dview, gview, G, C)
+                                    dview, gview, G, C, rnd)
+
+    def _plan(self, P: int, scoped) -> Callable:
+        """The accumulate pass for these slabs: one ``stream_stats``
+        backend per slab (autotuned once per shape bucket)."""
+        impls, slabs_key = [], []
+        for s in scoped:
+            # timing cap preserves the width residue mod chunk so
+            # alignment-based supports() checks see the true shape's
+            # divisibility, and the winner at ~4M cols is the winner at
+            # full width (same memory-bound regime).  When the chunk
+            # itself exceeds the cap no capped width can stay
+            # chunk-aligned — cap hard instead of synthesizing a spec
+            # wider than the slab (which would defeat the cap's whole
+            # point: select_impl_for times dense zeros of spec size).
+            w = s.width
+            if w > AUTOTUNE_CAP_COLS:
+                if self.chunk <= AUTOTUNE_CAP_COLS:
+                    w = min(w, (AUTOTUNE_CAP_COLS // self.chunk)
+                            * self.chunk + w % self.chunk)
+                else:
+                    w = AUTOTUNE_CAP_COLS
+            spec = jax.ShapeDtypeStruct((P, w), s.matrix.dtype)
+            impl = select_impl_for("stream_stats", spec, spec,
+                                   block_n=self.chunk)
+            true_spec = jax.ShapeDtypeStruct((P, s.width), s.matrix.dtype)
+            if not impl.ok_for(true_spec, true_spec, block_n=self.chunk):
+                # the capped pick cannot run the real slab (e.g. the
+                # pallas tile kernel on an unaligned width — its pad
+                # would be the O(P·n) copy this engine exists to
+                # avoid): take the streaming XLA path instead
+                with force_backend("xla", op="stream_stats"):
+                    impl = select_impl_for("stream_stats", spec, spec,
+                                           block_n=self.chunk)
+            impls.append(impl)
+            slabs_key.append((P, s.width, str(s.matrix.dtype)))
+        return _accum_for(P, tuple(slabs_key), self.chunk, tuple(impls))
 
 
 class StreamedRoundContext:
@@ -483,12 +485,14 @@ class StreamedRoundContext:
 
     def __init__(self, engine: StreamedRoundEngine, stacked_deltas: Pytree,
                  stacked_grads: Pytree, dview: ChunkedFlatView,
-                 gview: ChunkedFlatView, G: jax.Array, C: jax.Array):
+                 gview: ChunkedFlatView, G: jax.Array, C: jax.Array,
+                 round_id: int):
         self.engine = engine
         self._deltas, self._grads = stacked_deltas, stacked_grads
         self._dview, self._gview = dview, gview
         self.G, self.C = G, C
         self.P = dview.K
+        self.round = round_id
 
     # -- device-uplink decodes (dense-engine feature) ------------------------
 
@@ -507,15 +511,16 @@ class StreamedRoundContext:
 
     def compose_grads(self, refs, counts):
         refs = list(refs)
-        if all(_is_mix(r) for r in refs):
-            w = np.asarray(counts, np.float64)
-            w = w / max(float(w.sum()), 1e-12)
-            acc = sum(float(wi) * jnp.asarray(r.w, jnp.float32)
-                      for wi, r in zip(w, refs))
-            return RowMix(acc, refs[0].src)
-        vecs = tuple(self.materialize(r) for r in refs)
-        return _fused.weighted_mean_rows(
-            vecs, jnp.asarray(np.asarray(counts, np.float32)))
+        with spans.span("stream_compose", round=self.round, K=len(refs)):
+            if all(_is_mix(r) for r in refs):
+                w = np.asarray(counts, np.float64)
+                w = w / max(float(w.sum()), 1e-12)
+                acc = sum(float(wi) * jnp.asarray(r.w, jnp.float32)
+                          for wi, r in zip(w, refs))
+                return RowMix(acc, refs[0].src)
+            vecs = tuple(self.materialize(r) for r in refs)
+            return _fused.weighted_mean_rows(
+                vecs, jnp.asarray(np.asarray(counts, np.float32)))
 
     # -- tier stages ---------------------------------------------------------
 
@@ -529,73 +534,82 @@ class StreamedRoundContext:
 
     def gateway(self, idxs, *, solve_grad=None,
                 pool_scale: float = 1.0) -> Dict[str, Any]:
-        stage = tier_stage(self.P, len(idxs), self.engine.solve_cfg,
-                           self.engine.tier_mode, pool_scale=pool_scale,
-                           robust=self.engine.robust)
-        g_w = (None if solve_grad is None
-               else jnp.asarray(solve_grad.w, jnp.float32))
-        out = stage(self.G, self.C, jnp.asarray(np.asarray(idxs, np.int32)),
-                    jnp.ones((len(idxs),), jnp.float32), g_w)
+        with spans.span("stream_gateway", round=self.round, K=len(idxs)):
+            stage = gateway_stage_for(
+                self.P, len(idxs), self.engine.solve_cfg,
+                self.engine.tier_mode, pool_scale=pool_scale,
+                robust=self.engine.robust)
+            g_w = (None if solve_grad is None
+                   else jnp.asarray(solve_grad.w, jnp.float32))
+            out = stage(self.G, self.C,
+                        jnp.asarray(np.asarray(idxs, np.int32)),
+                        jnp.ones((len(idxs),), jnp.float32), g_w)
         return self._wrap(out)
 
     def merge(self, u_refs, g_refs, counts, *,
               solve_grad=None) -> Dict[str, Any]:
         u_refs, g_refs = list(u_refs), list(g_refs)
-        dense = (any(not _is_mix(r) for r in u_refs + g_refs)
-                 or (solve_grad is not None and not _is_mix(solve_grad)))
-        if dense:
-            # above a compression hop the children are decoded (n,) vectors:
-            # delegate to the fused stack-inside-jit stage over the small
-            # (#children, n) member set the dense pipeline also holds
-            stage = _fused.summary_stage(
-                len(u_refs), self.engine.n, self.engine.solve_cfg,
-                self.engine.tier_mode, sum_to=1.0, stack=True,
-                scope_key=self.engine._scope_key,
-                scope_idx=self.engine._scope_idx)
-            return stage(tuple(self.materialize(r) for r in u_refs),
-                         tuple(self.materialize(r) for r in g_refs),
-                         jnp.asarray(np.asarray(counts, np.float32)),
-                         None if solve_grad is None
-                         else self.materialize(solve_grad))
-        stage = merge_stage(self.P, len(u_refs), self.engine.solve_cfg,
-                            self.engine.tier_mode, sum_to=1.0)
-        g_w = (None if solve_grad is None
-               else jnp.asarray(solve_grad.w, jnp.float32))
-        out = stage(self.G, self.C, self._mix_matrix(u_refs),
-                    self._mix_matrix(g_refs),
-                    jnp.asarray(np.asarray(counts, np.float32)), g_w)
+        with spans.span("stream_merge", round=self.round, K=len(u_refs)):
+            dense = (any(not _is_mix(r) for r in u_refs + g_refs)
+                     or (solve_grad is not None
+                         and not _is_mix(solve_grad)))
+            if dense:
+                # above a compression hop the children are decoded (n,)
+                # vectors: delegate to the fused stack-inside-jit stage over
+                # the small (#children, n) member set the dense pipeline
+                # also holds
+                stage = _fused.summary_stage(
+                    len(u_refs), self.engine.n, self.engine.solve_cfg,
+                    self.engine.tier_mode, sum_to=1.0, stack=True,
+                    scope_key=self.engine._scope_key,
+                    scope_idx=self.engine._scope_idx)
+                return stage(tuple(self.materialize(r) for r in u_refs),
+                             tuple(self.materialize(r) for r in g_refs),
+                             jnp.asarray(np.asarray(counts, np.float32)),
+                             None if solve_grad is None
+                             else self.materialize(solve_grad))
+            stage = merge_stage_for(self.P, len(u_refs),
+                                    self.engine.solve_cfg,
+                                    self.engine.tier_mode, sum_to=1.0)
+            g_w = (None if solve_grad is None
+                   else jnp.asarray(solve_grad.w, jnp.float32))
+            out = stage(self.G, self.C, self._mix_matrix(u_refs),
+                        self._mix_matrix(g_refs),
+                        jnp.asarray(np.asarray(counts, np.float32)), g_w)
         return self._wrap(out)
 
     def cloud_raw(self, idxs, kind: str, *,
                   solve_scale: float = 1.0) -> Tuple[RowMix, Dict]:
-        stage = cloud_raw_stage(self.P, len(idxs), self.engine.solve_cfg,
-                                kind, solve_scale=solve_scale,
-                                robust=self.engine.robust)
-        out = stage(self.G, self.C,
-                    jnp.asarray(np.asarray(idxs, np.int32)),
-                    jnp.ones((len(idxs),), jnp.float32))
+        with spans.span("stream_cloud_raw", round=self.round, K=len(idxs)):
+            stage = cloud_raw_stage_for(
+                self.P, len(idxs), self.engine.solve_cfg, kind,
+                solve_scale=solve_scale, robust=self.engine.robust)
+            out = stage(self.G, self.C,
+                        jnp.asarray(np.asarray(idxs, np.int32)),
+                        jnp.ones((len(idxs),), jnp.float32))
         return RowMix(out["u_w"], "delta"), out["info"]
 
     def cloud_combo(self, u_refs, counts, ghat, *, kind: str = "combo",
                     override=None) -> Tuple[Any, Dict]:
         u_refs = list(u_refs)
-        dense = (override is not None
-                 or any(not _is_mix(r) for r in u_refs)
-                 or (ghat is not None and not _is_mix(ghat)))
-        if dense:
-            stage = _fused.cloud_stage(
-                len(u_refs), self.engine.n, self.engine.solve_cfg, kind,
-                stack=True, scope_key=self.engine._scope_key,
-                scope_idx=self.engine._scope_idx)
-            return stage(tuple(self.materialize(r) for r in u_refs),
-                         self.materialize(ghat),
-                         jnp.asarray(np.asarray(counts, np.float32)),
-                         override=override)
-        stage = cloud_combo_stage(self.P, len(u_refs),
-                                  self.engine.solve_cfg, kind)
-        out = stage(self.G, self.C, self._mix_matrix(u_refs),
-                    jnp.asarray(ghat.w, jnp.float32),
-                    jnp.asarray(np.asarray(counts, np.float32)))
+        with spans.span("stream_cloud", round=self.round, K=len(u_refs)):
+            dense = (override is not None
+                     or any(not _is_mix(r) for r in u_refs)
+                     or (ghat is not None and not _is_mix(ghat)))
+            if dense:
+                stage = _fused.cloud_stage(
+                    len(u_refs), self.engine.n, self.engine.solve_cfg, kind,
+                    stack=True, scope_key=self.engine._scope_key,
+                    scope_idx=self.engine._scope_idx)
+                return stage(tuple(self.materialize(r) for r in u_refs),
+                             self.materialize(ghat),
+                             jnp.asarray(np.asarray(counts, np.float32)),
+                             override=override)
+            stage = cloud_combo_stage_for(self.P, len(u_refs),
+                                          self.engine.solve_cfg, kind)
+            out = stage(self.G, self.C, self._mix_matrix(u_refs),
+                        jnp.asarray(ghat.w, jnp.float32),
+                        jnp.asarray(np.asarray(counts, np.float32)))
         return RowMix(out["eff_w"], "delta"), out["info"]
 
     # -- vector materialization / final apply --------------------------------
@@ -604,14 +618,15 @@ class StreamedRoundContext:
         if not _is_mix(ref):
             return ref
         view = self._dview if ref.src == "delta" else self._gview
-        with spans.span("stream_materialize", src=ref.src, P=self.P):
+        with spans.span("stream_materialize", round=self.round, src=ref.src,
+                        P=self.P):
             return _materialize_mix(tuple(s.matrix for s in view.slabs),
                                     jnp.asarray(ref.w, jnp.float32))
 
     def apply(self, params: Pytree, delta_ref) -> Pytree:
         if not _is_mix(delta_ref):
             return _fused.apply_delta(params, delta_ref)
-        with spans.span("stream_apply", P=self.P):
+        with spans.span("stream_apply", round=self.round, P=self.P):
             return _apply_mix(params, self._deltas,
                               jnp.asarray(delta_ref.w, jnp.float32),
                               self.engine.donate_params)

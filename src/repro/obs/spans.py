@@ -13,7 +13,8 @@ clocks at once:
     stamps it explicitly (``t_virtual=`` on :func:`begin`/:func:`end`,
     :func:`record_span` for transfers whose duration is known up front).
 
-Three entry points, all free on the noop path (one ``active`` check):
+Three entry points, all free on the noop path (an ``active`` check, and
+for :func:`span` a check that the profiler is off):
 
   * ``with span(name, **tags): ...`` — nested lifetimes.  Spans opened
     inside run as children: each carries a ``path`` like
@@ -30,6 +31,16 @@ Three entry points, all free on the noop path (one ``active`` check):
   * ``record_span(name, t0_virtual=, dur_virtual_s=, **tags)`` — a span
     whose interval is already known (the ``CommLedger``'s link transfers:
     virtual duration computed from bytes/bandwidth at record time).
+
+While the JAX profiler records, a nested span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro/<name>`` with its tags as the
+event's stats, tracker or no tracker: the span then sits in the profiler's
+``.xplane.pb`` beside the device's operations and the harness's ``bench/``
+spans, so an idle gap of the device can be put down to the host work that
+was running.  With no active tracker and the profiler off, :func:`span`
+returns a shared no-op context (two flag checks).  The flat ``begin`` /
+``end`` spans and :func:`record_span` are virtual-time lifetimes of the
+event scheduler and stay tracker-only.
 
 Every close emits ONE ``kind="span"`` event through the active tracker's
 ``log_span`` — the jsonl / in-memory / composite sinks of ``repro.obs``
@@ -49,7 +60,18 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .tracker import TrackedEvent, Tracker, current_tracker
+
+try:
+    # the profiler's own "is a trace being recorded" flag (jax 0.9): one
+    # C++ call, much cheaper than opening a TraceAnnotation that is off
+    from jax._src.lib import _profiler
+    _profiling: Callable[[], bool] = _profiler.TraceMe.is_enabled
+except (ImportError, AttributeError):   # else always open the annotation
+    def _profiling() -> bool:
+        return True
 
 # span-event keys that are structure, not caller tags
 RESERVED_KEYS = ("name", "path", "depth", "flat", "t0_wall", "dur_wall_s",
@@ -131,38 +153,97 @@ def current_path() -> str:
     return stack[-1].path if stack else ""
 
 
-@contextmanager
+# TraceMe encodes stats as ``name#k=v,k=v#``: these would split a value
+_STAT_SEPARATORS = str.maketrans({",": ";", "=": ":", "#": " "})
+
+
+def annotation(name: str, tags: Dict[str, Any]) -> TraceAnnotation:
+    """The profiler event ``repro/<name>`` with ``tags`` as its stats."""
+    return TraceAnnotation(
+        f"repro/{name}",
+        **{k: (v if isinstance(v, (int, float))
+               else str(v).translate(_STAT_SEPARATORS))
+           for k, v in tags.items()})
+
+
+class _NoopSpan:
+    """What :func:`span` returns when neither a tracker nor the profiler
+    would see the span: one shared instance, nothing recorded."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NOOP_SPAN = _NoopSpan()
+
+
+class _Span:
+    """An open nested span: a tracker handle (when a tracker is active)
+    and a profiler annotation (when the profiler records)."""
+
+    __slots__ = ("tracker", "name", "tags", "t_virtual", "clock", "handle",
+                 "annotation")
+
+    def __init__(self, tracker: Tracker, name: str,
+                 t_virtual: Optional[float],
+                 clock: Optional[Callable[[], float]], tags: Dict[str, Any]):
+        self.tracker, self.name, self.tags = tracker, name, tags
+        self.t_virtual, self.clock = t_virtual, clock
+        self.handle: Optional[SpanHandle] = None
+        self.annotation = None
+
+    def __enter__(self) -> Optional[SpanHandle]:
+        if self.tracker.active:
+            stack = _stack()
+            parent = stack[-1].path if stack else ""
+            t_virtual = self.t_virtual
+            if t_virtual is None:
+                t_virtual = (self.clock() if self.clock is not None
+                             else virtual_now())
+            self.handle = SpanHandle(
+                name=self.name,
+                path=f"{parent}/{self.name}" if parent else self.name,
+                depth=len(stack), t0_wall=time.perf_counter(),
+                t0_virtual=t_virtual, tags=dict(self.tags),
+                tracker=self.tracker)
+            stack.append(self.handle)
+        if _profiling():
+            self.annotation = annotation(self.name, self.tags)
+            self.annotation.__enter__()
+        return self.handle
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
+        h = self.handle
+        if h is not None:
+            if exc_type is not None:
+                h.tags.setdefault("error", exc_type.__name__)
+            _stack().pop()
+            _emit(h, time.perf_counter(),
+                  self.clock() if self.clock is not None else virtual_now())
+        return False
+
+
 def span(name: str, *, t_virtual: Optional[float] = None,
          clock: Optional[Callable[[], float]] = None,
-         **tags: Any) -> Iterator[Optional[SpanHandle]]:
-    """Record a nested span around the block.  Yields the handle (or None
-    on the noop path); callers may add tags via ``handle.tags[...] = ...``.
-    ``clock`` is a per-span virtual clock (e.g. ``lambda: scheduler.now``)
-    for call sites outside a :func:`use_virtual_clock` block.  Exceptions
-    close the span with an ``error`` tag and re-raise."""
+         **tags: Any) -> "_Span | _NoopSpan":
+    """A nested span around a ``with`` block.  The block gets the tracker
+    handle (None when no tracker is active); callers may add tags via
+    ``handle.tags[...] = ...``.  ``clock`` is a per-span virtual clock (e.g.
+    ``lambda: scheduler.now``) for call sites outside a
+    :func:`use_virtual_clock` block.  Exceptions close the span with an
+    ``error`` tag and re-raise.  While the profiler records, the block is
+    also the annotation ``repro/<name>`` with ``tags`` as its stats."""
     tr = current_tracker()
-    if not tr.active:
-        yield None
-        return
-    stack = _stack()
-    parent = stack[-1].path if stack else ""
-    if t_virtual is None:
-        t_virtual = clock() if clock is not None else virtual_now()
-    h = SpanHandle(name=name,
-                   path=f"{parent}/{name}" if parent else name,
-                   depth=len(stack), t0_wall=time.perf_counter(),
-                   t0_virtual=t_virtual,
-                   tags=dict(tags), tracker=tr)
-    stack.append(h)
-    try:
-        yield h
-    except BaseException as exc:
-        h.tags.setdefault("error", type(exc).__name__)
-        raise
-    finally:
-        stack.pop()
-        _emit(h, time.perf_counter(),
-              clock() if clock is not None else virtual_now())
+    if not tr.active and not _profiling():
+        return _NOOP_SPAN
+    return _Span(tr, name, t_virtual, clock, tags)
 
 
 def begin(name: str, *, t_virtual: Optional[float] = None,

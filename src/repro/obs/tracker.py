@@ -186,25 +186,33 @@ class CompositeTracker(Tracker):
 
 NOOP = NoopTracker()
 
-# The active tracker is thread-local so parallel test workers / background
-# eval threads cannot interleave scopes; the default everywhere is NOOP.
-_STATE = threading.local()
+
+class _TrackerStack(threading.local):
+    """The active tracker is thread-local so parallel test workers /
+    background eval threads cannot interleave scopes; the default
+    everywhere is NOOP.  Each thread starts with an empty stack (a plain
+    attribute read: ``getattr`` with a default on a ``threading.local``
+    costs an exception per miss, on every noop span)."""
+
+    def __init__(self) -> None:
+        self.stack: List[Tracker] = []
+
+
+_STATE = _TrackerStack()
 
 
 def current_tracker() -> Tracker:
     """The process-wide active tracker (``NOOP`` unless a ``use_tracker``
     context is open on this thread)."""
-    return getattr(_STATE, "stack", None)[-1] if getattr(
-        _STATE, "stack", None) else NOOP
+    stack = _STATE.stack
+    return stack[-1] if stack else NOOP
 
 
 @contextmanager
 def use_tracker(tracker: Tracker, *, finish: bool = True) -> Iterator[Tracker]:
     """Install ``tracker`` as :func:`current_tracker` for the block; nested
     contexts stack.  ``finish=True`` closes the tracker's sink on exit."""
-    stack = getattr(_STATE, "stack", None)
-    if stack is None:
-        stack = _STATE.stack = []
+    stack = _STATE.stack
     stack.append(tracker)
     try:
         yield tracker

@@ -12,6 +12,7 @@ import json
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -217,12 +218,129 @@ def test_flat_spans_do_not_corrupt_nesting():
 
 def test_spans_are_free_on_the_noop_path():
     assert current_tracker() is NOOP
+    # profiler off, no tracker: one shared no-op, nothing recorded
+    assert spans.span("x") is spans.span("w", round=3)
     with spans.span("x") as h:
         assert h is None
     assert spans.begin("y") is None
     spans.end(None, outcome="ignored")          # no-op, no error
     spans.record_span("z", t0_virtual=0.0, dur_virtual_s=1.0)
     assert spans.current_path() == ""
+
+
+def _profile(tmp_path, body):
+    """Run ``body`` under the JAX profiler as the benchmark harness records
+    (host tracer level 1) and return the host events named ``repro/...``
+    as (name, start_ns, end_ns, stats)."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro/"):
+                    a = int(e.start_ns)
+                    out.append((e.name, a, a + int(e.duration_ns),
+                                dict(e.stats)))
+    return sorted(out, key=lambda ev: ev[1])
+
+
+def test_span_reaches_the_profiler_trace_without_a_tracker(tmp_path):
+    assert current_tracker() is NOOP
+
+    def body():
+        with spans.span("outer", P=16, src="delta") as h:
+            assert h is None                    # no tracker handle
+            with spans.span("inner", round=3):
+                pass
+
+    events = _profile(tmp_path, body)
+    assert [e[0] for e in events] == ["repro/outer", "repro/inner"]
+    (_, a0, b0, outer), (_, a1, b1, inner) = events
+    assert outer == {"P": 16, "src": "delta"} and inner == {"round": 3}
+    assert a0 <= a1 <= b1 <= b0                 # nested spans nest in time
+
+
+def test_span_feeds_tracker_and_trace_at_once(tmp_path):
+    mem = InMemoryTracker()
+
+    def body():
+        with use_tracker(mem, finish=False):
+            with spans.span("solve", K=4):
+                pass
+
+    events = _profile(tmp_path, body)
+    assert [(e[0], e[3]) for e in events] == [("repro/solve", {"K": 4})]
+    (f,) = [span_fields(e) for e in mem.span_events()]
+    assert f["path"] == "solve" and span_tags(f) == {"K": 4}
+
+
+def test_compile_counter_and_its_trace_marker(tmp_path):
+    from repro.obs import compiles
+    x = jnp.ones((3,))                          # compiled before counting
+    fn = jax.jit(lambda v: v * 2 + 1)
+    before = compiles()
+    fn(x)
+    assert compiles() == before + 1
+    fn(x)
+    assert compiles() == before + 1             # cached: no compile
+
+    events = _profile(tmp_path, lambda: jax.jit(lambda v: v - 3)(x))
+    marks = [e for e in events if e[0] == "repro/compile"]
+    # a marker, not an interval: it holds no work of its own
+    assert marks and all(b - a < 1e6 for _, a, b, _ in marks)
+    stats = [m[3] for m in marks]
+    assert any(st["event"] == "/jax/core/compile/backend_compile_duration"
+               and st["seconds"] >= 0 for st in stats)
+
+
+def test_streamed_round_spans_share_a_round_id():
+    from repro.core.solve import SolveConfig
+    from repro.hier.streamed import StreamedRoundEngine
+    P = 4
+    key = jax.random.PRNGKey(0)
+    D = {"w": jax.random.normal(key, (P, 3, 5)),
+         "b": jax.random.normal(key, (P, 5))}
+    GM = jax.tree_util.tree_map(lambda v: -0.5 * v, D)
+    params = jax.tree_util.tree_map(lambda v: v[0], D)
+    eng = StreamedRoundEngine(params, SolveConfig(beta=5.0, ridge=1e-6),
+                              "contextual", chunk=8)
+
+    def one_round():
+        ctx = eng.begin_round(D, GM)
+        sums = [ctx.gateway(c) for c in ([0, 1], [2, 3])]
+        ghat = ctx.compose_grads([s["ghat"] for s in sums], [2.0, 2.0])
+        merged = ctx.merge([s["u_bar"] for s in sums],
+                           [s["ghat"] for s in sums], [2.0, 2.0])
+        ctx.cloud_raw([0, 1, 2, 3], "raw")
+        delta, _ = ctx.cloud_combo([s["u_bar"] for s in sums],
+                                   [2.0, 2.0], ghat)
+        assert merged["u_bar"].w.shape == (P,)
+        return ctx.apply(params, delta)
+
+    mem = InMemoryTracker()
+    with use_tracker(mem, finish=False):
+        one_round()
+        one_round()
+    fields = [span_fields(e) for e in mem.span_events()]
+    table = {"stream_views", "stream_plan", "stream_accumulate",
+             "stream_gateway", "stream_compose", "stream_merge",
+             "stream_cloud_raw", "stream_cloud", "stream_apply"}
+    assert table <= {f["name"] for f in fields}
+    by_round = {}
+    for f in fields:
+        by_round.setdefault(f["round"], set()).add(f["name"])
+    assert sorted(by_round) == [0, 1]
+    assert by_round[0] == by_round[1] and table <= by_round[0]
+    assert eng.rounds == 2
 
 
 def test_record_span_emits_known_virtual_interval():

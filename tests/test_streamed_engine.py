@@ -444,3 +444,57 @@ def test_streamed_never_builds_dense_round_matrix():
         streamed.select_impl_for = orig
     n = sum(l.size for l in jax.tree_util.tree_leaves(tmpl))
     assert seen and all(shape[1] < n for shape in seen)
+
+
+def _module_name(lowered) -> str:
+    return lowered.compiler_ir().operation.attributes["sym_name"].value
+
+
+def test_round_programs_keep_their_names():
+    """The device trace finds each program of a round by its module name
+    (``bench/metrics/`` reads ``accumulate``, ``apply_mix`` and
+    ``flat_view``): lower every program one streamed round runs, and pin
+    the names, so a rename fails here instead of zeroing a metric."""
+    from repro.core.flatten import flat_view_slab
+    stacked, grads = _stacked(P=4, leaves=((3, 5), (6,)))
+    params = _template(stacked)
+    cfg = SolveConfig(beta=5.0, ridge=1e-6)
+    eng = StreamedRoundEngine(params, cfg, "contextual", chunk=8)
+    ctx = eng.begin_round(stacked, grads)
+    sums = [ctx.gateway(c) for c in ([0, 1], [2, 3])]
+    ghat = ctx.compose_grads([s["ghat"] for s in sums], [2.0, 2.0])
+    delta, _ = ctx.cloud_combo([s["u_bar"] for s in sums], [2.0, 2.0], ghat)
+    ctx.apply(params, delta)
+
+    leaf = stacked["leaf0"]
+    slabs = ctx._dview.scoped_slabs
+    W = jnp.stack([jnp.asarray(s["u_bar"].w) for s in sums])
+    counts = jnp.asarray([2.0, 2.0])
+    programs = {
+        "jit_flat_view_slab": flat_view_slab.lower(leaf, 15),
+        "jit_accumulate": eng._plan(4, slabs).lower(
+            tuple(s.matrix for s in slabs),
+            tuple(ctx._gview.slabs[s.index].matrix for s in slabs)),
+        "jit_gateway_stage": streamed.gateway_stage_for(
+            4, 2, cfg, "contextual").lower(
+                ctx.G, ctx.C, jnp.asarray([0, 1], jnp.int32),
+                jnp.ones((2,)), None),
+        "jit_merge_stage": streamed.merge_stage_for(
+            4, 2, cfg, "contextual").lower(ctx.G, ctx.C, W, W, counts),
+        "jit_cloud_raw_stage": streamed.cloud_raw_stage_for(
+            4, 4, cfg, "raw").lower(ctx.G, ctx.C,
+                                    jnp.arange(4, dtype=jnp.int32),
+                                    jnp.ones((4,))),
+        "jit_cloud_combo_stage": streamed.cloud_combo_stage_for(
+            4, 2, cfg, "combo").lower(ctx.G, ctx.C, W,
+                                      jnp.asarray(ghat.w), counts),
+        "jit_apply_mix": streamed._apply_fn(False).lower(
+            params, stacked, jnp.asarray(delta.w)),
+    }
+    assert {k: _module_name(v) for k, v in programs.items()} == {
+        k: k for k in programs}
+    # the view's program is the eager reshape it replaced, renamed
+    eager = jax.jit(lambda v: jnp.reshape(v, (4, 15))).lower(leaf)
+    body = programs["jit_flat_view_slab"].as_text().replace(
+        "jit_flat_view_slab", "@")
+    assert body == eager.as_text().replace("jit__lambda", "@")
