@@ -201,22 +201,20 @@ class ChunkedFlatView:
 
 
 def mix_rows(weights: jax.Array, leaf: jax.Array) -> jax.Array:
-    """``Σ_k w_k · leaf[k]`` flattened to the leaf's (width,) columns, with
-    f32 accumulation and **no** materialized f32 upcast of the leaf — the
-    per-leaf primitive of the streamed combine pass (``α @ U`` one leaf at a
-    time).
+    """``Σ_k w_k · leaf[k]`` for ``weights (K,)`` and ``leaf (K, *S)`` →
+    ``(*S,)`` float32, for any ``S`` — the per-leaf primitive of the
+    streamed combine pass (``α @ U`` one leaf at a time).
 
-    The weights are cast to the leaf dtype so the contraction never copies
-    the leaf: for bf16 update leaves that rounds each f32 solve weight to 8
-    mantissa bits, a deliberate trade — second-order next to the bf16
-    quantization already baked into the update values themselves (f32
-    leaves contract exactly; the fused/streamed parity tests pin that
-    case)."""
-    m = jnp.reshape(leaf, (leaf.shape[0], -1))
-    out = jax.lax.dot_general(
-        weights.astype(m.dtype)[None, :], m, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return out[0]
+    Weights and accumulation are float32 on every backend (a bf16 leaf is
+    not multiplied by rounded weights).  The sum is a reduction over the
+    leading axis in the leaf's own shape: no reshape merges its minor
+    dimensions (on a TPU's tiled layout that is a relayout copy of the
+    whole leaf) and no ``dot_general`` with one output row (which the TPU
+    compiler rewrites as a multiply-reduce over an f32 copy of the leaf).
+    XLA fuses the upcast, the multiply and the reduce into one pass that
+    reads the leaf once."""
+    w = weights.astype(jnp.float32).reshape((-1,) + (1,) * (leaf.ndim - 1))
+    return jnp.sum(w * leaf.astype(jnp.float32), axis=0)
 
 
 def tree_add(a: Pytree, b: Pytree) -> Pytree:
@@ -244,10 +242,9 @@ def tree_weighted_sum(trees: Iterable[Pytree], weights: jax.Array) -> Pytree:
 
 def stacked_weighted_sum(stacked: Pytree, weights: jax.Array) -> Pytree:
     """Same as :func:`tree_weighted_sum` but for pre-stacked pytrees whose
-    leaves have a leading K axis.  Contracts via :func:`mix_rows` (a dot
-    with f32 accumulation) instead of broadcasting ``leaf * w`` — no
-    K-times-leaf temporary, which matters at transformer width."""
+    leaves have a leading K axis.  Each leaf is summed by :func:`mix_rows`
+    (float32 weights and accumulation, a leading-axis reduction in the
+    leaf's own shape) and cast back to the leaf's dtype."""
     def comb(leaf):
-        return jnp.reshape(mix_rows(weights, leaf),
-                           leaf.shape[1:]).astype(leaf.dtype)
+        return mix_rows(weights, leaf).astype(leaf.dtype)
     return jax.tree_util.tree_map(comb, stacked)

@@ -313,8 +313,7 @@ def _apply_fn(donate: bool) -> Callable:
     @partial(jax.jit, donate_argnums=(0,) if donate else ())
     def apply_mix(params, stacked, w):
         return jax.tree_util.tree_map(
-            lambda p, s: (p + jnp.reshape(mix_rows(w, s), p.shape)
-                          ).astype(p.dtype),
+            lambda p, s: (p + mix_rows(w, s)).astype(p.dtype),
             params, stacked)
     return apply_mix
 

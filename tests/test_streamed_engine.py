@@ -10,6 +10,7 @@ sketch/EF compressed composition, scope × chunk interaction, the
 peak-bytes estimator, and engine auto-selection.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -498,3 +499,105 @@ def test_round_programs_keep_their_names():
     body = programs["jit_flat_view_slab"].as_text().replace(
         "jit_flat_view_slab", "@")
     assert body == eager.as_text().replace("jit__lambda", "@")
+
+
+# ------------------------------------------------------------- combine
+
+# leaf shapes S of rank 1-4 (stacked as (P, *S)): minor dimensions that are
+# and are not multiples of 128, the TPU's lane width
+_MIX_SHAPES = [(256,), (130,), (4, 128), (3, 130), (2, 8, 256), (3, 5, 7),
+               (2, 3, 8, 128), (2, 3, 7, 130)]
+
+
+def _mix_case(P, shape, dtype, seed=0):
+    key = jax.random.PRNGKey(seed)
+    leaf = jax.random.normal(key, (P,) + shape, jnp.float32).astype(dtype)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (P,), jnp.float32)
+    leaf64 = np.asarray(leaf.astype(jnp.float32), np.float64)
+    w64 = np.asarray(w, np.float64)
+    want = np.tensordot(w64, leaf64, axes=1)
+    # f32 tolerance: a few roundings of each term's magnitude
+    bound = 4 * P * np.finfo(np.float32).eps * np.tensordot(
+        np.abs(w64), np.abs(leaf64), axes=1)
+    return leaf, w, want, bound
+
+
+def _assert_near(got, want, bound):
+    err = np.abs(np.asarray(got, np.float64) - want)
+    assert np.all(err <= bound + 1e-30), float(np.max(err - bound))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("P", [3, 16])
+@pytest.mark.parametrize("shape", _MIX_SHAPES, ids=str)
+def test_combine_matches_float64_sum(shape, P, dtype):
+    """``mix_rows``, the engine's ``apply_mix`` and ``stacked_weighted_sum``
+    give ``Σ_k w_k·leaf[k]`` in the leaf's own shape, to f32 accuracy (the
+    weights are not rounded to a bf16 leaf's dtype)."""
+    from repro.core.flatten import mix_rows, stacked_weighted_sum
+    leaf, w, want, bound = _mix_case(P, shape, dtype)
+    got = mix_rows(w, leaf)
+    assert got.shape == shape and got.dtype == jnp.float32
+    _assert_near(got, want, bound)
+
+    p = jax.random.normal(jax.random.PRNGKey(7), shape, jnp.float32)
+    new = streamed._apply_fn(False)({"x": p}, {"x": leaf}, w)["x"]
+    assert new.shape == shape and new.dtype == jnp.float32
+    p64 = np.asarray(p, np.float64)
+    _assert_near(new, p64 + want,
+                 bound + 2 * np.finfo(np.float32).eps * np.abs(p64 + want))
+
+    summed = stacked_weighted_sum({"x": leaf}, w)["x"]
+    assert summed.shape == shape and summed.dtype == dtype
+    # the sum is f32; only its cast back to the leaf's dtype rounds it
+    _assert_near(summed.astype(jnp.float32), want,
+                 bound + float(jnp.finfo(dtype).eps) * np.abs(want))
+
+
+@pytest.mark.parametrize("P", [3, 16])
+def test_materialize_mix_matches_flat_view(P):
+    """The compressed pipeline's full-width vector is ``w @`` the dense
+    (P, n) matrix that ``ChunkedFlatView.materialize()`` implies."""
+    tree = {f"l{i}": jax.random.normal(jax.random.PRNGKey(i), (P,) + s,
+                                       jnp.float32).astype(jnp.bfloat16)
+            for i, s in enumerate(_MIX_SHAPES)}
+    view = ChunkedFlatView(tree)
+    w = jax.random.normal(jax.random.PRNGKey(99), (P,), jnp.float32)
+    got = streamed._materialize_mix(tuple(s.matrix for s in view.slabs), w)
+    dense = np.asarray(view.materialize(), np.float64)
+    assert got.shape == (view.n,)
+    w64 = np.asarray(w, np.float64)
+    _assert_near(got, w64 @ dense,
+                 4 * P * np.finfo(np.float32).eps * (np.abs(w64) @ np.abs(dense)))
+
+
+def _reshapes(text):
+    """(operand dims, result dims) of every ``stablehlo.reshape``."""
+    pat = re.compile(r"stablehlo\.reshape .*: \(tensor<([0-9x]*)x?\w+>\) "
+                     r"-> tensor<([0-9x]*)x?\w+>")
+
+    def dims(s):
+        return [int(d) for d in s.split("x") if d]
+    return [(dims(a), dims(b)) for a, b in pat.findall(text)]
+
+
+def test_apply_mix_keeps_stacked_leaf_layout():
+    """The combine of a stacked (P, E, d, f) bf16 leaf is a leading-axis
+    reduction in the leaf's own shape: no reshape merges its two minor
+    dimensions (a relayout copy of the whole leaf on a TPU) and no
+    ``dot_general`` (an f32 copy of the leaf there)."""
+    P, E, d, f = 16, 2, 24, 136
+    params = {"e": jnp.zeros((E, d, f), jnp.float32),
+              "n": jnp.zeros((f,), jnp.float32)}
+    stacked = {"e": jnp.zeros((P, E, d, f), jnp.bfloat16),
+               "n": jnp.zeros((P, f), jnp.bfloat16)}
+    lowered = streamed._apply_fn(False).lower(params, stacked,
+                                              jnp.ones((P,), jnp.float32))
+    assert _module_name(lowered) == "jit_apply_mix"
+    text = lowered.as_text()
+    assert "dot_general" not in text and "stablehlo.dot" not in text
+    merged = [(a, b) for a, b in _reshapes(text)
+              if a[-2:] == [d, f] and b[-2:] != [d, f]]
+    assert not merged, merged
+    assert "stablehlo.reduce" in text

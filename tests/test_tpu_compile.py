@@ -13,6 +13,7 @@
 import functools
 import importlib.util
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -114,6 +115,29 @@ def test_stream_stats_tile_is_decoupled_from_chunk():
     assert pallas_tile(16, jnp.float32, CHUNK) == 1 << 15
     assert pallas_tile(16, jnp.bfloat16, 2048) == 2048
     assert pallas_tile(1000, jnp.float32, CHUNK) == 512
+
+
+def test_combine_reads_stacked_leaves_in_their_layout_on_v5e(one_chip):
+    """The streamed combine (``jit_apply_mix``) at OLMoE widths with two
+    experts: on the TPU's tiled layout no whole-leaf ``copy`` (a relayout)
+    or ``convert`` (an f32 copy of the bf16 updates) runs outside a fusion,
+    and the program holds no scratch the size of a stacked leaf."""
+    from repro.hier import streamed
+    P, shapes = 16, {"experts": (2, 2048, 1024), "attn": (2048, 2048),
+                     "norm": (2048,)}
+    params = {k: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for k, s in shapes.items()}
+    stacked = {k: jax.ShapeDtypeStruct((P,) + s, jnp.bfloat16,
+                                       sharding=one_chip)
+               for k, s in shapes.items()}
+    w = jax.ShapeDtypeStruct((P,), jnp.float32, sharding=one_chip)
+    compiled = streamed._apply_fn(False).lower(params, stacked, w).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert not re.findall(r"= \S+ (?:copy|convert)\(", entry)
+    leaf_bytes = P * 2 * 2048 * 1024 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
 
 
 # ------------------------------------------------------ chip_smoke on the CPU
