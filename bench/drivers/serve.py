@@ -2,6 +2,10 @@
 continuous-batching ``DecodeEngine``, which serves a mixture-of-experts
 language model published on a ``ModelBus``.
 
+Set-up first maps the choices the configuration states beyond its sizes
+(the top-k gate's renormalisation, the scope of the q/k norm) to the
+program's ``ArchConfig`` by one table (``CHOICES``), and refuses, before
+any weights are made, a configuration the program cannot express.
 Weights are made here from the seed, on the device, in one jitted call, in
 the program's parameter layout (``jax.eval_shape`` of the program's own
 init) and in the types it serves them in.  Traffic comes from the mix's
@@ -29,6 +33,7 @@ choice at every position.  The others are logged.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import re
@@ -48,11 +53,39 @@ NORMS = ("ln1", "ln2", "final_norm", "q_norm", "k_norm")
 
 # ------------------------------------------------------------------ model
 
+# Each choice the reference makes beyond the sizes: what the configuration
+# states it by, its value in ``ref.Arch``, the ``ArchConfig`` field that
+# carries it to the program, and what the program does where ``ArchConfig``
+# has no such field (``models/moe.py`` renormalises the top-k gate;
+# ``models/attention.py::_project_qkv`` normalises q and k per head).
+CHOICES = (
+    ("norm_topk_prob (top-k gate renormalised)", "norm_topk",
+     "norm_topk_prob", True),
+    ("model_type (scope of the q/k RMSNorm)", "qk_scope", "qk_norm_scope",
+     "head"),
+)
+
+
 def program_config(cfg: Dict[str, Any]):
     """The program's ``ArchConfig`` for the configuration file's published
-    sizes."""
+    sizes and choices (``CHOICES``).  A choice that ``ArchConfig`` has no
+    field for, and that differs from what the program does without one, is
+    refused: the program cannot serve it as the configuration states."""
     from repro.models.config import ArchConfig
     a = ref.arch(cfg)
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    choices, refused = {}, []
+    for stated_by, attr, field, without in CHOICES:
+        value = getattr(a, attr)
+        if field in fields:
+            choices[field] = value
+        elif value != without:
+            refused.append(f"{stated_by} is {value!r}, but ArchConfig has "
+                           f"no field {field!r} (without it the program "
+                           f"serves {without!r})")
+    if refused:
+        raise BenchError(f"configuration {cfg['name']}: the program cannot "
+                         "serve it as stated: " + "; ".join(refused))
     return ArchConfig(
         name=cfg["name"], family="moe", num_layers=a.layers, d_model=a.d,
         num_heads=a.heads, num_kv_heads=a.kv_heads, head_dim=a.head_dim,
@@ -60,7 +93,7 @@ def program_config(cfg: Dict[str, Any]):
         activation=cfg["hidden_act"], num_experts=a.experts,
         experts_per_token=a.top_k, num_shared_experts=0, norm_eps=a.eps,
         tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"])
+        dtype=cfg["torch_dtype"], **choices)
 
 
 def _leaf_name(path) -> str:

@@ -14,7 +14,12 @@ lives in a file of its own, found by name:
     cell's correctness check compares, with the readings it was set from
 
 So a later cell, configuration, mix or metric is new files plus a new entry
-in ``BENCHMARK.json``: nothing here changes.
+in ``BENCHMARK.json``: nothing here changes.  For a serving configuration
+that holds where the plain reference knows its ``model_type``
+(``bench/reference/moe_lm.py``); a new model type needs a change to the
+reference.  ``bench/drivers/serve.py`` carries the configuration's
+published choices to the program and refuses at set-up one that the
+program cannot express.
 """
 from __future__ import annotations
 

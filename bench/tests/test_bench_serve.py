@@ -1,8 +1,10 @@
 """The serving cell on the CPU at a small size, with the device guard off:
 traffic from the mix and the seed alone, a sound run is correct, and ``correct`` comes
-out false with the timed path broken underneath, with the control (the
-reference in float8) in the program's place, and where the program departs
-from the architecture the configuration states."""
+out false with the timed path broken underneath and with the control (the
+reference in float8) in the program's place.  The configuration's published
+choices reach the program's ``ArchConfig`` where it has a field for them,
+and a choice it cannot express is refused at set-up."""
+import dataclasses
 import json
 import time
 
@@ -149,9 +151,28 @@ def _qk_norm_over_the_projection(monkeypatch):
     monkeypatch.setattr(attention, "rms_norm", rms_norm)
 
 
+def _gate_not_renormalised(monkeypatch):
+    """The top-k gate served as the router gives it, where the configuration
+    states renormalisation: each token's expert output scaled by the sum
+    of its top-k probabilities."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import transformer
+    orig = transformer.moe_forward
+
+    def unnormalised(cfg, params, x):
+        out, aux = orig(cfg, params, x)
+        probs = jax.nn.softmax(x.astype(jnp.float32) @ params["router"], -1)
+        mass = jax.lax.top_k(probs, cfg.experts_per_token)[0].sum(
+            -1, keepdims=True)
+        return (out * mass).astype(out.dtype), aux
+    monkeypatch.setattr(transformer, "moe_forward", unnormalised)
+
+
 @pytest.mark.parametrize("fault", [_stale_cache, _half_the_batch,
                                    _altered_token,
-                                   _qk_norm_over_the_projection])
+                                   _qk_norm_over_the_projection,
+                                   _gate_not_renormalised])
 def test_faults_are_not_correct(monkeypatch, fault):
     fault(monkeypatch)
     out = _run(_cell(rate=120.0))    # every slot in use
@@ -169,16 +190,76 @@ def test_the_control_fails_the_limits(monkeypatch):
     assert not out["correct"], out["checks"]
 
 
+# ------------------------------------------------------------------ choices
+
+# the program's fields for the choices ``serve.CHOICES`` maps
+CHOICE_FIELDS = ("norm_topk_prob", "qk_norm_scope")
+OLMOE_CHOICES = {"model_type": "olmoe", "norm_topk_prob": False}
+
+
+def _without_the_choices():
+    """A stand-in ``ArchConfig`` with no field for either choice."""
+    from repro.models.config import ArchConfig
+    return dataclasses.make_dataclass("ArchConfig", [
+        (f.name, f.type, dataclasses.field(
+            default=f.default, default_factory=f.default_factory))
+        for f in dataclasses.fields(ArchConfig)
+        if f.name not in CHOICE_FIELDS], frozen=True)
+
+
+def _with_the_choices():
+    """A stand-in ``ArchConfig`` with a field for each choice, which reads
+    ``None`` unless a value is passed."""
+    from repro.models.config import ArchConfig
+    return dataclasses.make_dataclass(
+        "ArchConfig", [(f, object, None) for f in CHOICE_FIELDS],
+        bases=(ArchConfig,), frozen=True)
+
+
+def test_the_chat_configuration_is_served_as_before():
+    """The chat cell's ``ArchConfig`` is the one its driver built from
+    literal choices before the table: the same programs and compile-cache
+    keys."""
+    from repro.models.config import ArchConfig
+    cfg = harness.find_cell(harness.load_benchmark(), CHAT).config
+    assert S.program_config(cfg) == ArchConfig(
+        name="qwen3-30b-a3b.serve-stage", family="moe", num_layers=8,
+        d_model=2048, num_heads=32, num_kv_heads=4, head_dim=128, d_ff=768,
+        vocab_size=151936, rope_theta=1e6, qk_norm=True, activation="silu",
+        num_experts=128, experts_per_token=8, num_shared_experts=0,
+        norm_eps=1e-6, tie_embeddings=False, dtype="bfloat16")
+
+
 @pytest.mark.parametrize("departure", [
-    {"norm_topk_prob": False},
-    {"model_type": "olmoe", "norm_topk_prob": False}])
-def test_the_program_departs_from_olmoe_as_published(departure):
-    """The program always renormalises the top-k gate and normalises q and
-    k per head: a configuration stating OLMoE's choices (no
-    renormalisation; q/k norms over the projections) is not what it
-    serves."""
-    out = _run(_cell(**departure))
-    assert not out["correct"], out["checks"]
+    {"norm_topk_prob": False}, OLMOE_CHOICES])
+def test_a_choice_the_program_cannot_express_is_refused(monkeypatch,
+                                                        departure):
+    """Under a program whose ``ArchConfig`` has no field for the gate's
+    renormalisation or the q/k norm's scope, a configuration stating
+    OLMoE's choices (no renormalisation; q/k norms over the projections)
+    is refused at set-up, before any weights are made, naming each
+    choice."""
+    from repro.models import config
+    monkeypatch.setattr(config, "ArchConfig", _without_the_choices())
+    made = []
+    monkeypatch.setattr(S, "make_params", lambda *a: made.append(a))
+    with pytest.raises(harness.BenchError) as e:
+        _run(_cell(**departure))
+    msg = str(e.value)
+    assert "qwen3-30b-a3b.serve-stage" in msg
+    assert "norm_topk_prob (top-k gate renormalised) is False" in msg
+    assert ("'qk_norm_scope'" in msg) == ("model_type" in departure)
+    assert not made
+
+
+@pytest.mark.parametrize("choices, want", [
+    ({}, (True, "head")), (OLMOE_CHOICES, (False, "projection"))])
+def test_the_choices_reach_a_program_that_has_the_fields(monkeypatch,
+                                                         choices, want):
+    from repro.models import config
+    monkeypatch.setattr(config, "ArchConfig", _with_the_choices())
+    pcfg = S.program_config({**_cell().config, **choices})
+    assert (pcfg.norm_topk_prob, pcfg.qk_norm_scope) == want
 
 
 # ------------------------------------------------------------------ reference
@@ -220,7 +301,8 @@ def test_reference_matches_transformers(model_type):
     cfg = {**_cell().config, "model_type": model_type,
            "norm_topk_prob": model_type == "qwen3_moe"}
     a = ref.arch(cfg)
-    params = S.make_params(S.program_config(cfg), 5)
+    # random weights in the program's layout, which the sizes alone set
+    params = S.make_params(S.program_config(_cell().config), 5)
     w = S.reference_weights(a, params)
     common = dict(vocab_size=a.vocab, hidden_size=a.d,
                   num_hidden_layers=a.layers, num_attention_heads=a.heads,
